@@ -40,10 +40,12 @@ Environment knobs:
   REPRO_SIM_KERNELS      decision-path kernel dispatch (resolved per call
                          by `repro.kernels.etf_ft.ops.kernel_mode`):
                          0/off = inline jnp, 1/auto (default) = Pallas on
-                         TPU / fused XLA elsewhere, pallas = force Pallas
-                         (interpret mode off-TPU), xla = force fused XLA
+                         TPU / fused XLA elsewhere, pallas = native Pallas
+                         (TPU only), pallas-interpret = Pallas through the
+                         interpreter, xla = force fused XLA
   REPRO_BENCH_CACHE_DIR  autotune-cache location (default
-                         ~/.cache/repro)
+                         `.autotune_cache/` at the repo root, beside the
+                         JAX compile cache of `repro.core.compile_cache`)
 """
 from __future__ import annotations
 
@@ -55,8 +57,8 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from repro.core import campaign as camp, das, oracle, simulator as sim, \
-    workloads
+from repro.core import campaign as camp, compile_cache, das, oracle, \
+    simulator as sim, workloads
 
 def _env_int(name: str, default: int) -> int:
     """Positive-integer env knob; garbage or non-positive values are
@@ -113,7 +115,7 @@ _BATCH_DEFAULT_CANDIDATES = (16, 32, 64, 128)
 
 def _autotune_cache_path() -> str:
     root = os.environ.get("REPRO_BENCH_CACHE_DIR", "").strip() \
-        or os.path.join(os.path.expanduser("~"), ".cache", "repro")
+        or os.path.join(compile_cache.REPO_ROOT, ".autotune_cache")
     return os.path.join(root, "autotune.json")
 
 

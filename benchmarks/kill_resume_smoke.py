@@ -2,13 +2,17 @@
 
 Proves the crash-safety claim end to end with a real SIGKILL:
 
-  1. compute an uninterrupted reference sweep in-process (`sim.run_batch`);
-  2. launch a child process running the same sweep as a checkpointed
-     campaign, throttled (`chunk_delay_s`) so chunks land one at a time;
-  3. SIGKILL the child once some — but not all — chunks are checkpointed;
+  1. launch a child process running a checkpointed campaign, throttled
+     (`chunk_delay_s`) so chunks land one at a time;
+  2. SIGKILL the child once some — but not all — chunks are checkpointed;
+  3. compute an uninterrupted reference sweep in-process (`sim.run_batch`);
   4. resume the campaign in-process and assert (a) completed chunks were
      reused, not recomputed, and (b) every `SimResult` field is
      byte-identical to the uninterrupted reference.
+
+A device belongs to one process at a time, so the parent imports nothing
+that touches JAX until the child is dead: until then it only watches the
+checkpoint directory.
 
     PYTHONPATH=src python -m benchmarks.kill_resume_smoke [--dir DIR]
 
@@ -25,11 +29,6 @@ import sys
 import tempfile
 import time
 
-import numpy as np
-
-from repro.core import campaign as camp, simulator as sim, workloads
-
-MODE = sim.MODE_LUT
 N_INSTANCES = 5
 CELLS = [(mi, ri) for mi in range(4) for ri in (0, 5, 9, 13)]  # 16 scenarios
 BATCH = 2                                                      # -> 8 chunks
@@ -37,13 +36,15 @@ CHUNK_DELAY_S = 0.6
 
 
 def _workloads():
+    from repro.core import workloads
     suite = workloads.default_suite(n_instances=N_INSTANCES)
     return [suite.build(mi, ri) for mi, ri in CELLS]
 
 
 def child(cdir: str) -> None:
     """Run the campaign slowly so the parent can SIGKILL it mid-grid."""
-    camp.run_campaign(MODE, _workloads(), batch_size=BATCH,
+    from repro.core import campaign as camp, simulator as sim
+    camp.run_campaign(sim.MODE_LUT, _workloads(), batch_size=BATCH,
                       checkpoint_dir=cdir, chunk_delay_s=CHUNK_DELAY_S)
 
 
@@ -52,12 +53,9 @@ def _chunk_files(cdir: str):
 
 
 def main(cdir: str) -> None:
-    wls = _workloads()
     n_chunks = -(-len(CELLS) // BATCH)
-    print(f"# reference sweep: {len(CELLS)} scenarios, {n_chunks} chunks")
-    ref = sim.run_batch(MODE, wls, batch_size=BATCH)
-
-    print("# launching child campaign (throttled)...")
+    print(f"# launching child campaign (throttled): {len(CELLS)} "
+          f"scenarios, {n_chunks} chunks")
     proc = subprocess.Popen(
         [sys.executable, "-m", "benchmarks.kill_resume_smoke",
          "--child", cdir],
@@ -90,8 +88,16 @@ def main(cdir: str) -> None:
         raise SystemExit("child finished before the kill — not a mid-grid "
                          "interruption; widen CHUNK_DELAY_S")
 
+    # the child is dead: only now may this process touch a device
+    import numpy as np
+
+    from repro.core import campaign as camp, simulator as sim
+    wls = _workloads()
+    print("# reference sweep in-process...")
+    ref = sim.run_batch(sim.MODE_LUT, wls, batch_size=BATCH)
     print("# resuming in-process...")
-    out = camp.run_campaign(MODE, wls, batch_size=BATCH, checkpoint_dir=cdir)
+    out = camp.run_campaign(sim.MODE_LUT, wls, batch_size=BATCH,
+                            checkpoint_dir=cdir)
     assert out.stats["chunks_reused"] >= done - 1, out.stats
     assert out.stats["chunks_reused"] < n_chunks, out.stats
     assert out.stats["chunks_computed"] + out.stats["chunks_reused"] \

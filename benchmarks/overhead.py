@@ -1,7 +1,7 @@
 """Scheduling-overhead microbenchmark (paper I / IV-C anchors): per-decision
 latency and energy of LUT, ETF, the DAS classifier, plus the measured
-wall-time of the ETF finish-time search (jnp oracle vs Pallas kernel in
-interpret mode — the TPU kernel's semantics)."""
+wall-time of the ETF finish-time search (jnp oracle and fused XLA
+everywhere, the native Pallas kernels on a TPU)."""
 from __future__ import annotations
 
 import time
@@ -37,33 +37,31 @@ def run(csv=False):
     }
 
     # ETF finish-time search wall-time, batch of 64 decisions: the jnp
-    # oracle AND the kernel dispatch path (Pallas native on TPU, interpret
-    # elsewhere — interpret is a correctness path, so its time is reported
-    # for scaling context, not as a win)
+    # oracle and the fused XLA formulation everywhere, the Pallas kernels
+    # only where they run natively (a TPU). Interpreter timings say
+    # nothing about the chip, so they are never taken. These shapes
+    # compile for the chip (`tests/test_tpu_compile.py`).
     B, R, P = 64, 64, 19
     key = jax.random.PRNGKey(0)
     avail = jax.random.uniform(key, (B, R, P)) * 10
     free = jax.random.uniform(key, (B, P)) * 10
     ex = jax.random.uniform(key, (B, R, P)) * 5
     now = jnp.zeros((B,))
-    interpret = jax.default_backend() != "tpu"
-    kreps = 3 if interpret else 20
-    rows["etf_ft_jnp_us_per_batch64"] = _time_us(
-        jax.jit(er.etf_ft_reference), avail, free, ex, now)
-    rows["etf_ft_kernel_us_per_batch64"] = _time_us(
-        lambda *a: ek.etf_ft_search(*a, interpret=interpret),
-        avail, free, ex, now, reps=kreps)
-
-    # scenario-batched masked variant (the decision hot path the
-    # simulator routes through under REPRO_SIM_KERNELS)
     slot_ok = jnp.ones((B, R), bool)
     alive = jnp.ones((B, P), bool)
+    native = eo.kernel_mode("auto") == "pallas"
+    rows["etf_ft_jnp_us_per_batch64"] = _time_us(
+        jax.jit(er.etf_ft_reference), avail, free, ex, now)
+    # scenario-batched masked variant (the decision hot path the
+    # simulator routes through under REPRO_SIM_KERNELS)
     rows["etf_ft_masked_xla_us_per_batch64"] = _time_us(
         jax.jit(er.etf_ft_masked_reference),
         avail, free, ex, now, slot_ok, alive)
-    rows["etf_ft_masked_kernel_us_per_batch64"] = _time_us(
-        lambda *a: ek.etf_ft_search_masked(*a, interpret=interpret),
-        avail, free, ex, now, slot_ok, alive, reps=kreps)
+    if native:
+        rows["etf_ft_pallas_us_per_batch64"] = _time_us(
+            ek.etf_ft_search, avail, free, ex, now)
+        rows["etf_ft_masked_pallas_us_per_batch64"] = _time_us(
+            ek.etf_ft_search_masked, avail, free, ex, now, slot_ok, alive)
 
     for k, v in rows.items():
         if csv:

@@ -114,6 +114,8 @@ def main(argv=None) -> None:
     only = set(args.only.split(",")) if args.only else None
 
     from benchmarks import common
+    from repro.core import compile_cache
+    compile_cache.enable()
     if args.resume:
         common.set_campaign_dir(args.resume)
 

@@ -29,7 +29,8 @@ if args.fresh:
 
 cfg = configs.get_smoke_config(args.arch, n_layers=4, d_model=128,
                                vocab=512)
-mesh = jax.make_mesh((1, 1), ("data", "model"))
+mesh = jax.make_mesh((1, 1), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 data = Prefetcher(SyntheticLM(vocab=cfg.vocab, batch=8, seq_len=128,
                               n_codebooks=cfg.n_codebooks))
 tcfg = tr.TrainerConfig(

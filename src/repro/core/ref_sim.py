@@ -329,3 +329,39 @@ def simulate_ref(mode: int, wl: FlatWorkload,
         "n_recovered": n_recovered,
         "job_dropped": job_dropped,
     }
+
+
+def disagreements(res, ref: Dict, n_tasks: int) -> List[str]:
+    """How a float32 `SimResult` (one scenario) departs from this float64
+    reference beyond the agreed tolerances; empty when it agrees.
+
+    Exact finish-time ties that fp32 and fp64 break differently may
+    cascade a small bounded deviation into downstream tasks (comm-cost
+    deltas), and a tied placement may land on a cluster with different
+    power — hence fractions for finish times and PE choices and a loose
+    bound on task energy. The tolerances are set for the differential
+    test's 10-frame streams; the cascades grow with stream length, and
+    at 60 frames a few cells exceed them.
+    """
+    out = []
+    if int(res.n_done) != ref["n_done"]:
+        out.append(f"n_done {int(res.n_done)} != {ref['n_done']}")
+    fin = np.asarray(res.finish)[:n_tasks]
+    fin_ref = ref["finish"][:n_tasks]
+    atol = 1e-3 * max(1.0, float(np.abs(fin_ref).max()))
+    diff = np.abs(fin - fin_ref)
+    if not (diff <= atol).mean() >= 0.98:
+        out.append(f"only {(diff <= atol).mean():.4f} of finish times "
+                   f"within {atol:g} us")
+    if not diff.max() < 0.25:
+        out.append(f"max finish-time deviation {diff.max():g} us")
+    pe_match = (np.asarray(res.pe_of)[:n_tasks] == ref["pe_of"][:n_tasks])
+    if not pe_match.mean() > 0.9:
+        out.append(f"only {pe_match.mean():.4f} of PE choices match")
+    avg, avg_ref = float(res.avg_exec_us), ref["avg_exec_us"]
+    if not abs(avg - avg_ref) <= max(1e-4 * abs(avg_ref), 1e-3):
+        out.append(f"avg_exec_us {avg!r} vs {avg_ref!r}")
+    en, en_ref = float(res.task_energy_uj), ref["task_energy_uj"]
+    if not abs(en - en_ref) <= max(0.05 * abs(en_ref), 1e-12):
+        out.append(f"task_energy_uj {en!r} vs {en_ref!r}")
+    return out

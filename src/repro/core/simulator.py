@@ -81,11 +81,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec
 
-try:  # jax >= 0.4.x; pmap fallback below when absent
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    _shard_map = None
-
 from repro.core import faults as flt
 from repro.core import soc
 from repro.core.workloads import FlatWorkload, FRAME_KBITS
@@ -162,11 +157,18 @@ class DTree(NamedTuple):
     leaf: jax.Array    # [4] i32 in {0, 1}
 
     def predict(self, f: jax.Array) -> jax.Array:
-        right0 = f[self.feat[0]] >= self.thr[0]
-        node = jnp.where(right0, 2, 1)
-        rightc = f[self.feat[node]] >= self.thr[node]
-        idx = jnp.where(right0, 2, 0) + rightc.astype(jnp.int32)
-        return self.leaf[idx]
+        # Every node's test is evaluated and the path selected with
+        # one-hot masks, not gathers: on a TPU v5e the batched DAS
+        # program with the gather form (`f[feat[node]]`, `leaf[idx]`)
+        # and the Pallas decision kernels never finished at 140 lanes
+        # (it did at 560); this form does at both. Adding zeros is
+        # exact, so the selected values are the features themselves.
+        hot = self.feat[:, None] == jnp.arange(f.shape[-1])       # [3, F]
+        val = jnp.where(hot, f[None, :], 0).sum(axis=1)           # [3]
+        right = val >= self.thr
+        rightc = jnp.where(right[0], right[2], right[1])
+        idx = jnp.where(right[0], 2, 0) + rightc.astype(jnp.int32)
+        return jnp.where(jnp.arange(4) == idx, self.leaf, 0).sum()
 
 
 def always_fast_tree() -> DTree:
@@ -1500,18 +1502,22 @@ def simulate_batch(mode: int, params: SimParams, wls: FlatWorkload,
                                step_budget, _kops.kernel_mode(kernels),
                                fcaps)
     if telemetry is not None:
-        telemetry.append(_telemetry_record(res, tel))
+        telemetry.append(_telemetry_record(res, tel,
+                                           len(tel.loop_trips.devices())))
     return res
 
 
-def _telemetry_record(res: SimResult, tel: BatchTelemetry) -> dict:
-    """Host-side occupancy record for one engine call (blocks on `tel`)."""
+def _telemetry_record(res: SimResult, tel: BatchTelemetry,
+                      devices: int) -> dict:
+    """Host-side occupancy record for one engine call (blocks on `tel`);
+    `devices` is how many devices the call's lanes ran on."""
     loop = np.asarray(jax.device_get(tel.loop_trips))
     act = np.asarray(jax.device_get(tel.active_trips))
     events = np.asarray(jax.device_get(res.n_iters))
     allocated = int(loop.sum())
     return {
         "lanes": int(loop.shape[0]),
+        "devices": devices,
         "lane_trips": allocated,            # sum over lanes of shard trips
         "active_trips": int(act.sum()),     # trips with the lane still live
         "events": int(events.sum()),        # retired simulator events
@@ -1572,61 +1578,35 @@ def _sharded_batch_fn(mode: int, tree_axis, thr_axis, plan_axis,
     """Compiled scenario-sharded batch engine over a fixed device tuple.
 
     Shards the leading scenario axis of every batched argument across
-    `devices` with `shard_map` (or a `jax.pmap` fallback). Each shard runs
-    its own independent masked while loop — lanes never interact, so there
-    is no collective in the body and no cross-device sync until the caller
-    fetches: per-scenario results are bit-identical regardless of device
-    count. Cached per (mode, batched-axes, devices) so every fixed-shape
-    chunk of a sweep reuses one executable.
+    `devices` with `jax.shard_map`. Each shard runs its own independent
+    masked while loop — lanes never interact, so there is no collective
+    in the body and no cross-device sync until the caller fetches:
+    per-scenario results are bit-identical regardless of device count.
+    Cached per (mode, batched-axes, devices) so every fixed-shape chunk
+    of a sweep reuses one executable.
     """
-    D = len(devices)
-
     def call(params, wls, tree, rate_threshold, plan):
         return _simulate_batch_impl(mode, params, wls, tree, rate_threshold,
                                     plan, tree_axis, thr_axis, plan_axis,
                                     step_budget, kernels, fcaps)
 
-    if _shard_map is not None:
-        mesh = Mesh(np.array(devices), ("s",))
-        sh = PartitionSpec("s")
-        rep = PartitionSpec()
-        t_spec = sh if tree_axis == 0 else rep
-        r_spec = sh if thr_axis == 0 else rep
-        if has_plan:
-            fn = _shard_map(call, mesh=mesh,
-                            in_specs=(rep, sh, t_spec, r_spec,
-                                      sh if plan_axis == 0 else rep),
-                            out_specs=sh, check_rep=False)
-            return jax.jit(fn)
-        fn = _shard_map(
-            lambda params, wls, tree, rt: call(params, wls, tree, rt, None),
-            mesh=mesh, in_specs=(rep, sh, t_spec, r_spec), out_specs=sh,
-            check_rep=False)
-        return jax.jit(lambda params, wls, tree, rt, plan:
-                       fn(params, wls, tree, rt))
-
-    # pmap fallback: fold the device axis out of / back into the scenario
-    # axis ([B] -> [D, B/D] -> engine -> [B]); in_axes mirror the specs
-    pm = jax.pmap(call, devices=devices,
-                  in_axes=(None, 0, tree_axis, thr_axis,
-                           plan_axis if has_plan else None))
-
-    def fold(x):
-        return x.reshape((D, x.shape[0] // D) + x.shape[1:])
-
-    def wrapped(params, wls, tree, rate_threshold, plan):
-        wls = jax.tree_util.tree_map(fold, wls)
-        if tree_axis == 0:
-            tree = jax.tree_util.tree_map(fold, tree)
-        if thr_axis == 0:
-            rate_threshold = fold(rate_threshold)
-        if has_plan and plan_axis == 0:
-            plan = jax.tree_util.tree_map(fold, plan)
-        out = pm(params, wls, tree, rate_threshold, plan)
-        return jax.tree_util.tree_map(
-            lambda x: x.reshape((-1,) + x.shape[2:]), out)
-
-    return wrapped
+    mesh = Mesh(np.array(devices), ("s",))
+    sh = PartitionSpec("s")
+    rep = PartitionSpec()
+    t_spec = sh if tree_axis == 0 else rep
+    r_spec = sh if thr_axis == 0 else rep
+    if has_plan:
+        fn = jax.shard_map(call, mesh=mesh,
+                           in_specs=(rep, sh, t_spec, r_spec,
+                                     sh if plan_axis == 0 else rep),
+                           out_specs=sh, check_vma=False)
+        return jax.jit(fn)
+    fn = jax.shard_map(
+        lambda params, wls, tree, rt: call(params, wls, tree, rt, None),
+        mesh=mesh, in_specs=(rep, sh, t_spec, r_spec), out_specs=sh,
+        check_vma=False)
+    return jax.jit(lambda params, wls, tree, rt, plan:
+                   fn(params, wls, tree, rt))
 
 
 def run(mode: int, wl: FlatWorkload, params: SimParams | None = None,
@@ -1673,7 +1653,7 @@ def run_batch(mode: int, wls, params: SimParams | None = None,
     of the same chunk size) reuses ONE compiled executable instead of
     retracing for the remainder chunk. `devices` (or `REPRO_BENCH_DEVICES`,
     default: all of `jax.devices()`) shards the scenario axis of each chunk
-    across devices with `shard_map` (`jax.pmap` fallback); lanes are
+    across devices with `jax.shard_map`; lanes are
     independent, so per-scenario results are bit-identical for any
     `batch_size` and any device count. Chunks are dispatched
     asynchronously and fetched once at the end, overlapping host-side tree
@@ -1753,11 +1733,12 @@ def run_batch(mode: int, wls, params: SimParams | None = None,
         rt = sl(rate_threshold) if thr_b else rate_threshold
         pl = jax.tree_util.tree_map(sl, plan) if plan_b else plan
         chunks.append(dispatch(params, part, t, rt, pl))
+    n_devs = [len(tel_c.loop_trips.devices()) for _, tel_c in chunks]
     # one blocking fetch for the whole sweep (dispatches above are async)
     chunks = jax.device_get(chunks)
     if telemetry is not None:
-        for res_c, tel_c in chunks:
-            telemetry.append(_telemetry_record(res_c, tel_c))
+        for (res_c, tel_c), d in zip(chunks, n_devs):
+            telemetry.append(_telemetry_record(res_c, tel_c, d))
     return jax.tree_util.tree_map(
         lambda *xs: np.concatenate(xs, axis=0)[:n],
         *[res_c for res_c, _ in chunks])

@@ -5,14 +5,15 @@ now) + exec[r, p] over (ready tasks x PEs) and takes the argmin. On the
 DSSoC this runs on a Cortex-A53 in ~65 ns; the TPU-native adaptation is a
 dense masked min-reduction:
 
-  * PE axis padded to the 128-lane VPU width, ready axis tiled by block_r
-    (sublane-aligned),
-  * one fused pass computes FT and a flat argmin via an index-encoded
-    min-reduction (value * P + index packing avoided: we reduce value and
-    index side by side),
-  * grid = (n_batch,) for vmapped scheduling sweeps (the simulator's
-    40-workload x 14-rate evaluation runs thousands of independent
-    decisions).
+  * PE axis padded to the 128-lane VPU width, one [R, Pp] tile per
+    scenario, grid = (n_scenarios,);
+  * the argmin is two 2-D reductions on that tile — the minimum, then the
+    smallest flat index (`r * Pp + p`, from 2-D iotas) whose entry equals
+    it — so there is no gather and no scalar store;
+  * every operand is 3-D with the scenario axis leading, so each block
+    spans its array's full last two dimensions (the (8, 128) tiling rule
+    never applies); scalars travel as [S, 1, 1], per-slot masks as
+    [S, R, 1], and each result leaves as a lane-wide [1, 128] row.
 
 inf entries (PE cannot run the task type / empty ready slots) never win.
 
@@ -29,6 +30,9 @@ Two kernels serve the simulator's decision hot path (dispatched by
     task the max over its predecessors of (pred finish + NoC transfer
     when the predecessor ran on a different cluster), fused over the
     [K, MP, P] contribution tensor in one pass.
+
+`etf_ft_search` is the unmasked search: the masked kernel with every slot
+and PE enabled.
 """
 from __future__ import annotations
 
@@ -40,7 +44,6 @@ from jax.experimental import pallas as pl
 
 BIG = 3.4e38
 LANES = 128         # VPU lane width: the PE axis pads up to this
-SUBLANES = 8        # f32 sublane tile height (ready axis alignment)
 
 # One grid step of the search kernel owns a [R, Pp] block. Interpret mode
 # evaluates the grid with a Python interpreter, so its cost scales with
@@ -55,67 +58,38 @@ def _pad_lanes(p: int) -> int:
     return max(LANES, -(-p // LANES) * LANES)
 
 
-def _etf_kernel(avail_ref, free_ref, exec_ref, now_ref, out_ref):
-    avail = avail_ref[0]                       # [R, P]
-    free = free_ref[0]                         # [1, P]
-    exec_t = exec_ref[0]                       # [R, P]
-    now = now_ref[0, 0]
-    ft = jnp.maximum(jnp.maximum(avail, free), now) + exec_t
-    ft = jnp.where(jnp.isfinite(ft), ft, BIG)
-    flat = ft.reshape(-1)
-    idx = jnp.argmin(flat)
-    out_ref[0, 0] = flat[idx]
-    out_ref[0, 1] = idx.astype(jnp.float32)
+def _lead(shape):
+    """BlockSpec for one scenario's slice of a [S, *shape] operand."""
+    return pl.BlockSpec((1,) + shape, lambda b: (b,) + (0,) * len(shape))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def etf_ft_search(avail, free, exec_t, now, *, interpret=False):
-    """avail [B, R, P], free [B, P], exec_t [B, R, P], now [B].
-    Returns (ft_min [B], slot [B], pe [B]). Lanes padded to 128."""
-    B, R, P = avail.shape
-    Pp = _pad_lanes(P)
-    pad = ((0, 0), (0, 0), (0, Pp - P))
-    avail_p = jnp.pad(avail, pad, constant_values=jnp.inf)
-    exec_p = jnp.pad(exec_t, pad, constant_values=jnp.inf)
-    free_p = jnp.pad(free[:, None, :], pad, constant_values=jnp.inf)
-
-    out = pl.pallas_call(
-        _etf_kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, R, Pp), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1, Pp), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, R, Pp), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 2), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 2), jnp.float32),
-        interpret=interpret,
-    )(avail_p, free_p, exec_p, now[:, None])
-
-    ft_min = out[:, 0]
-    flat_idx = out[:, 1].astype(jnp.int32)
-    return ft_min, flat_idx // Pp, flat_idx % Pp
+def _min2d(x):
+    """Minimum of a 2-D tile, kept as a [1, 1] vector."""
+    return jnp.min(jnp.min(x, axis=1, keepdims=True), axis=0, keepdims=True)
 
 
 # ---------------------------------------------------------------------------
 # scenario-batched masked decision search
 # ---------------------------------------------------------------------------
 def _etf_masked_kernel(avail_ref, free_ref, exec_ref, now_ref, sok_ref,
-                       alive_ref, out_ref):
+                       alive_ref, ft_ref, idx_ref):
     avail = avail_ref[0]                       # [R, Pp]
     free = free_ref[0]                         # [1, Pp]
     exec_t = exec_ref[0]                       # [R, Pp]
-    now = now_ref[0, 0]
-    sok = sok_ref[0]                           # [R] f32 0/1
+    now = now_ref[0]                           # [1, 1]
+    sok = sok_ref[0]                           # [R, 1] f32 0/1
     alive = alive_ref[0]                       # [1, Pp] f32 0/1
     ft = jnp.maximum(jnp.maximum(avail, free), now) + exec_t
-    ok = (sok[:, None] > 0) & (alive > 0) & jnp.isfinite(ft)
+    ok = (sok > 0) & (alive > 0) & jnp.isfinite(ft)
     ft = jnp.where(ok, ft, BIG)
-    flat = ft.reshape(-1)
-    idx = jnp.argmin(flat)
-    out_ref[0, 0] = flat[idx]
-    out_ref[0, 1] = idx.astype(jnp.float32)
+    R, Pp = ft.shape
+    mn = _min2d(ft)                            # [1, 1]
+    flat = (jax.lax.broadcasted_iota(jnp.int32, (R, Pp), 0) * Pp
+            + jax.lax.broadcasted_iota(jnp.int32, (R, Pp), 1))
+    # first global minimum: the smallest flat index attaining `mn`
+    idx = _min2d(jnp.where(ft == mn, flat, R * Pp))
+    ft_ref[0] = jnp.broadcast_to(mn, (1, LANES))
+    idx_ref[0] = jnp.broadcast_to(idx, (1, LANES))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -136,27 +110,34 @@ def etf_ft_search_masked(avail, free, exec_t, now, slot_ok, pe_alive, *,
     exec_p = jnp.pad(exec_t, pad, constant_values=jnp.inf)
     free_p = jnp.pad(free[:, None, :], pad, constant_values=jnp.inf)
     alive_p = jnp.pad(pe_alive.astype(jnp.float32)[:, None, :], pad)
-    sok = slot_ok.astype(jnp.float32)
+    sok = slot_ok.astype(jnp.float32)[:, :, None]
 
-    out = pl.pallas_call(
+    ft, idx = pl.pallas_call(
         _etf_masked_kernel,
         grid=(S,),
-        in_specs=[
-            pl.BlockSpec((1, R, Pp), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1, Pp), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, R, Pp), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-            pl.BlockSpec((1, R), lambda b: (b, 0)),
-            pl.BlockSpec((1, 1, Pp), lambda b: (b, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 2), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((S, 2), jnp.float32),
+        in_specs=[_lead((R, Pp)), _lead((1, Pp)), _lead((R, Pp)),
+                  _lead((1, 1)), _lead((R, 1)), _lead((1, Pp))],
+        out_specs=[_lead((1, LANES)), _lead((1, LANES))],
+        out_shape=[jax.ShapeDtypeStruct((S, 1, LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((S, 1, LANES), jnp.int32)],
         interpret=interpret,
-    )(avail_p, free_p, exec_p, now[:, None], sok, alive_p)
+    )(avail_p, free_p, exec_p, now[:, None, None], sok, alive_p)
 
-    ft_min = out[:, 0]
-    flat_idx = out[:, 1].astype(jnp.int32)
+    ft_min = ft[:, 0, 0]
+    flat_idx = idx[:, 0, 0]
     return ft_min, flat_idx // Pp, flat_idx % Pp, ft_min < BIG
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def etf_ft_search(avail, free, exec_t, now, *, interpret=False):
+    """avail [B, R, P], free [B, P], exec_t [B, R, P], now [B].
+    Returns (ft_min [B], slot [B], pe [B]): the masked search with every
+    slot and PE enabled."""
+    B, R, P = avail.shape
+    ft_min, slot, pe, _ = etf_ft_search_masked(
+        avail, free, exec_t, now, jnp.ones((B, R), bool),
+        jnp.ones((B, P), bool), interpret=interpret)
+    return ft_min, slot, pe
 
 
 # ---------------------------------------------------------------------------
@@ -169,12 +150,12 @@ def _push_kernel(pfin_ref, cost_ref, pcl_ref, pv_ref, pecl_ref, base_ref,
     pcl = pcl_ref[0]                           # [K, MP] f32 cluster ids
     pv = pv_ref[0]                             # [K, MP] f32 0/1
     pecl = pecl_ref[0]                         # [Pp] f32 cluster ids
-    base = base_ref[0]                         # [K]
+    base = base_ref[0]                         # [K, 1]
     cross = (pcl[:, :, None] != pecl[None, None, :]).astype(jnp.float32)
     contrib = jnp.where(pv[:, :, None] > 0,
                         pfin[:, :, None] + cost[:, :, None] * cross,
                         -BIG)                  # [K, MP, Pp]
-    out_ref[0] = jnp.maximum(contrib.max(axis=1), base[:, None])
+    out_ref[0] = jnp.maximum(contrib.max(axis=1), base)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -197,17 +178,13 @@ def push_rows(pfin, cost, pcl, pv, pe_cluster, bases, *, interpret=False):
     out = pl.pallas_call(
         _push_kernel,
         grid=(S,),
-        in_specs=[
-            pl.BlockSpec((1, K, MP), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, K, MP), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, K, MP), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, K, MP), lambda b: (b, 0, 0)),
+        in_specs=[_lead((K, MP))] * 4 + [
             pl.BlockSpec((1, Pp), lambda b: (0, 0)),
-            pl.BlockSpec((1, K), lambda b: (b, 0)),
+            _lead((K, 1)),
         ],
-        out_specs=pl.BlockSpec((1, K, Pp), lambda b: (b, 0, 0)),
+        out_specs=_lead((K, Pp)),
         out_shape=jax.ShapeDtypeStruct((S, K, Pp), jnp.float32),
         interpret=interpret,
     )(pfin, cost, pcl.astype(jnp.float32), pv.astype(jnp.float32), pecl,
-      bases)
+      bases[:, :, None])
     return out[:, :, :P]

@@ -8,8 +8,10 @@ the `REPRO_SIM_KERNELS` knob is on. Dispatch rule:
     * ``0`` / ``off``      -> simulator keeps its inline jnp path
     * ``1`` / ``auto`` (default) -> Pallas kernels native on TPU, the
       single fused XLA formulation (`ref.py`) everywhere else
-    * ``pallas``           -> force the Pallas kernels even off-TPU
-      (interpret mode; slow — CI correctness runs only)
+    * ``pallas``           -> the native Pallas kernels; raises off-TPU
+    * ``pallas-interpret`` -> the Pallas kernels through the interpreter
+      on any backend (slow — CI correctness runs only)
+    * ``xla``              -> the fused XLA formulation on any backend
 
 The resolved mode is threaded into the jit'd simulator as a *static*
 argument by `run` / `run_batch` / `simulate_batch`, so flipping the env
@@ -53,7 +55,8 @@ def kernel_mode(raw: str | None = None) -> str:
     Idempotent: resolved modes pass through unchanged, so callers may
     hand either the raw knob value or an already-resolved mode. `xla`
     forces the fused XLA formulation even on TPU; `pallas-interpret`
-    forces the Pallas kernels through the interpreter on any backend.
+    forces the Pallas kernels through the interpreter on any backend;
+    `pallas` off a TPU is an error, never a silent downgrade.
     """
     if raw is None:
         raw = os.environ.get("REPRO_SIM_KERNELS", "1")
@@ -64,7 +67,12 @@ def kernel_mode(raw: str | None = None) -> str:
         return raw
     on_tpu = jax.default_backend() == "tpu"
     if raw == "pallas":
-        return "pallas" if on_tpu else "pallas-interpret"
+        if not on_tpu:
+            raise ValueError(
+                f"REPRO_SIM_KERNELS=pallas needs a TPU (backend is "
+                f"{jax.default_backend()!r}); ask for 'pallas-interpret' "
+                "to run the kernels through the interpreter")
+        return "pallas"
     if raw in _AUTO:
         return "pallas" if on_tpu else "xla"
     raise ValueError(
@@ -125,9 +133,10 @@ def interpret_batch_limit(r: int, p: int) -> int:
     return max(1, cells // block)
 
 
-def etf_ft(avail, free, exec_t, now, *, interpret=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def etf_ft(avail, free, exec_t, now, *, interpret: bool = False):
+    """Unmasked batched search (`kernel.etf_ft_search`). Interpret mode is
+    asked for by name; above `interpret_batch_limit` it falls back to the
+    jnp reference and tallies `etf_ft_ref_fallback`."""
     B, R, P = avail.shape
     if interpret and B > interpret_batch_limit(R, P):
         DISPATCH_COUNT["etf_ft_ref_fallback"] += 1

@@ -10,10 +10,16 @@ from __future__ import annotations
 import jax
 
 
+def _auto(n: int) -> tuple:
+    """Auto axis types: `jax.make_mesh` defaults to Explicit axes, which
+    the trainer's sharding rules (`parallel/sharding.py`) do not use."""
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(shape)))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
@@ -21,7 +27,8 @@ def make_local_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, n // data)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 # TPU v5e hardware constants used by the roofline analysis

@@ -171,7 +171,7 @@ def test_run_kernels_pallas_interpret_matches(mode):
     r0 = sim.run(mode, wl, PARAMS, tree=tree, rate_threshold=500.0,
                  kernels="off")
     rp = sim.run(mode, wl, PARAMS, tree=tree, rate_threshold=500.0,
-                 kernels="pallas")  # off-TPU -> pallas-interpret
+                 kernels="pallas-interpret")
     for name in sim.SimResult._fields:
         a, b = np.asarray(getattr(r0, name)), np.asarray(getattr(rp, name))
         assert a.tobytes() == b.tobytes(), (mode, name, a, b)
@@ -187,6 +187,7 @@ def test_run_batch_kernels_telemetry():
     assert sum(t["events"] for t in tel) == int(np.asarray(r.n_iters).sum())
     for t in tel:
         assert t["lanes"] == 2
+        assert t["devices"] == 1
         assert 0 < t["active_trips"] <= t["lane_trips"]
         assert 0 < t["occupancy"] <= 1.0
 

@@ -45,6 +45,32 @@ def test_depth2_array_lowering_matches_host_predict():
     assert (dev == host[:200]).all()
 
 
+def _walk(feat, thr, leaf, f):
+    """Plain depth-2 walk: root, then the child the root's test picks."""
+    node = 2 if f[feat[0]] >= thr[0] else 1
+    right = f[feat[node]] >= thr[node]
+    return int(leaf[(2 if node == 2 else 0) + int(right)])
+
+
+@hypothesis.settings(max_examples=60, deadline=None)
+@hypothesis.given(seed=st.integers(0, 2**31 - 1))
+def test_dtree_predict_matches_plain_walk(seed):
+    # feature values drawn from the thresholds themselves and +-inf, so
+    # exact ties (`>=` at the threshold) are common; batched under vmap
+    # as the simulator's engine runs it
+    import jax
+    rng = np.random.RandomState(seed)
+    F, n = 7, 64
+    feat = rng.randint(0, F, 3).astype(np.int32)
+    thr = rng.choice([-1.5, 0.0, 2.0, 4.0, np.inf], 3).astype(np.float32)
+    leaf = rng.randint(0, 2, 4).astype(np.int32)
+    pool = np.concatenate([thr, [-np.inf, np.inf, -0.0, 3.0]])
+    x = rng.choice(pool, (n, F)).astype(np.float32)
+    tree = DTree(jnp.asarray(feat), jnp.asarray(thr), jnp.asarray(leaf))
+    dev = np.asarray(jax.vmap(tree.predict)(jnp.asarray(x)))
+    assert dev.tolist() == [_walk(feat, thr, leaf, row) for row in x]
+
+
 def test_lr_learns_linear_concept():
     rng = np.random.RandomState(1)
     x = rng.randn(3000, 3).astype(np.float32)

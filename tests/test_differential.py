@@ -1,7 +1,6 @@
 """Differential test: the jittable lax.while_loop simulator vs an
 independently-written pure-Python reference (core/ref_sim.py) must agree on
 per-task schedules for LUT / ETF / ETF-ideal across workloads and rates."""
-import numpy as np
 import pytest
 
 from repro.core import ref_sim, simulator as sim, workloads
@@ -21,24 +20,7 @@ def test_jax_sim_matches_reference(mix, rate, mode):
     r_jax = sim.run(mode, wl, PARAMS)
     r_ref = ref_sim.simulate_ref(mode, wl)
 
-    assert int(r_jax.n_done) == r_ref["n_done"]
-    nt = int(wl.n_tasks)
-    fin_jax = np.asarray(r_jax.finish)[:nt]
-    fin_ref = r_ref["finish"][:nt]
-    # fp32 sim vs fp64 reference: tight agreement for ~all tasks; exact
-    # finish-time ties broken differently may cascade a small bounded
-    # deviation into a handful of downstream tasks (comm-cost deltas)
-    atol = 1e-3 * max(1.0, float(np.abs(fin_ref).max()))
-    diff = np.abs(fin_jax - fin_ref)
-    assert (diff <= atol).mean() >= 0.98, diff.max()
-    assert diff.max() < 0.25, diff.max()
-    # PE assignments: exact except where fp32 vs fp64 breaks an exact
-    # finish-time tie differently — matching finish times (asserted above)
-    # prove any divergent choice achieved the identical FT, i.e. a tie.
-    pe_match = (np.asarray(r_jax.pe_of)[:nt] == r_ref["pe_of"][:nt])
-    assert pe_match.mean() > 0.9, pe_match.mean()
-    assert float(r_jax.avg_exec_us) == pytest.approx(
-        r_ref["avg_exec_us"], rel=1e-4, abs=1e-3)
-    # tied placements may land on clusters with different power
-    assert float(r_jax.task_energy_uj) == pytest.approx(
-        r_ref["task_energy_uj"], rel=0.05)
+    # fp32 sim vs fp64 reference within the tolerances `disagreements`
+    # states (tight finish times for ~all tasks; exact ties broken
+    # differently may move a handful of placements)
+    assert ref_sim.disagreements(r_jax, r_ref, int(wl.n_tasks)) == []

@@ -263,9 +263,15 @@ def test_kernel_mode_resolution(monkeypatch):
     assert km("pallas-interpret") == "pallas-interpret"
     on_tpu = jax.default_backend() == "tpu"
     assert km("auto") == ("pallas" if on_tpu else "xla")
-    assert km("pallas") == ("pallas" if on_tpu else "pallas-interpret")
+    if on_tpu:
+        assert km("pallas") == "pallas"
+    else:
+        # native Pallas off-TPU is an error, never a silent interpret run
+        with pytest.raises(ValueError, match="pallas-interpret"):
+            km("pallas")
     # idempotent on resolved modes
-    for m in ("off", "xla", "pallas", "pallas-interpret"):
+    for m in ("off", "xla", "pallas-interpret") + (("pallas",) if on_tpu
+                                                  else ()):
         assert km(km(m)) == km(m)
     monkeypatch.setenv("REPRO_SIM_KERNELS", "off")
     assert km() == "off"

@@ -139,7 +139,8 @@ def test_dead_pe_degraded_etf_tie_breaks_kernels_on():
     for wl in WLS[:3]:
         r0 = sim.run(sim.MODE_ETF, wl, PARAMS, plan=plan, kernels="off")
         rx = sim.run(sim.MODE_ETF, wl, PARAMS, plan=plan, kernels="xla")
-        rp = sim.run(sim.MODE_ETF, wl, PARAMS, plan=plan, kernels="pallas")
+        rp = sim.run(sim.MODE_ETF, wl, PARAMS, plan=plan,
+                     kernels="pallas-interpret")
         # the alive mask constrained choices: never-alive PEs never chosen
         pe_of = np.asarray(r0.pe_of)
         assert not np.isin(pe_of[pe_of >= 0], dead_from_t0).any()
@@ -160,8 +161,11 @@ def test_multi_device_mesh_really_shards():
         pytest.skip("single-device process; CI runs this with 4 host "
                     "devices via XLA_FLAGS")
     ref = sim.run_batch(sim.MODE_LUT, WLS, PARAMS, devices=1)
+    tel = []
     shd = sim.run_batch(sim.MODE_LUT, WLS, PARAMS, batch_size=len(WLS),
-                        devices=N_DEV)
+                        devices=N_DEV, telemetry=tel)
+    # every chunk's lanes were spread over all N_DEV devices
+    assert [t["devices"] for t in tel] == [N_DEV] * len(tel)
     np.testing.assert_array_equal(np.asarray(ref.avg_exec_us),
                                   np.asarray(shd.avg_exec_us))
     np.testing.assert_array_equal(np.asarray(ref.finish),

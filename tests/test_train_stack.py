@@ -15,6 +15,9 @@ from repro.parallel import compression
 from repro.train import optimizer as optim
 from repro.train import trainer as tr
 
+# `jax.make_mesh` defaults to Explicit axes; the trainer shards with Auto
+AUTO2 = (jax.sharding.AxisType.Auto,) * 2
+
 
 def test_adamw_decreases_quadratic():
     cfg = optim.AdamWConfig(lr_peak=0.1, warmup_steps=5, total_steps=100,
@@ -77,7 +80,7 @@ def test_checkpoint_prune(tmp_path):
 
 
 def test_trainer_failure_recovery(tmp_path):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=AUTO2)
     cfg = configs.get_smoke_config("phi3-mini-3.8b", n_layers=2,
                                    d_model=64, vocab=128)
     tc = tr.TrainerConfig(total_steps=40, ckpt_every=10,
@@ -94,7 +97,7 @@ def test_trainer_failure_recovery(tmp_path):
 
 
 def test_trainer_resume_from_checkpoint(tmp_path):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=AUTO2)
     cfg = configs.get_smoke_config("phi3-mini-3.8b", n_layers=2,
                                    d_model=64, vocab=128)
     oc = optim.AdamWConfig(lr_peak=5e-3, warmup_steps=5, total_steps=30)
@@ -156,11 +159,11 @@ def test_int8_compression_accuracy():
 
 def test_compressed_psum_shard_map():
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     x = jnp.arange(8, dtype=jnp.float32)
 
-    f = shard_map(lambda v: compression.compressed_psum(v, "data"),
+    f = jax.shard_map(lambda v: compression.compressed_psum(v, "data"),
                   mesh=mesh, in_specs=P("data"), out_specs=P("data"))
     out = f(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x), atol=0.05)
