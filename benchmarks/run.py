@@ -87,19 +87,6 @@ def _env_record() -> dict:
     }
 
 
-def _kernel_record() -> dict:
-    """Which decision-path backend ran, how often each primitive traced,
-    and how often the whole simulator retraced (a nonzero retrace count
-    across a warm sweep session is a caching bug)."""
-    from repro.core import simulator as sim
-    from repro.kernels.etf_ft import ops as kops
-    return {
-        "mode": kops.kernel_mode(),
-        "dispatch_count": dict(kops.DISPATCH_COUNT),
-        "trace_count": dict(sim.TRACE_COUNT),
-    }
-
-
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--csv", action="store_true",
@@ -146,7 +133,6 @@ def main(argv=None) -> None:
             "env": _env_record(),
             "derived": _derived(results),
             "campaign": common.campaign_stats(),
-            "kernels": _kernel_record(),
             "sections": results,
         }
         # atomic write (temp + rename): a crash mid-dump never leaves a

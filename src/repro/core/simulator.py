@@ -72,8 +72,10 @@ bit-identical. Batched sweeps accept a plan with a leading scenario axis
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
+import time
 from typing import NamedTuple
 
 import jax
@@ -83,7 +85,7 @@ from jax.sharding import Mesh, PartitionSpec
 
 from repro.core import faults as flt
 from repro.core import soc
-from repro.core.workloads import FlatWorkload, FRAME_KBITS
+from repro.core.workloads import FlatWorkload, FRAME_KBITS, stack_workloads
 from repro.kernels.etf_ft import ops as _kops
 
 MODE_LUT = 0
@@ -115,6 +117,10 @@ SEG = 32            # fin_run segment size for the two-level next-completion
 RING = 8            # data-rate shift register entries (paper: 8x16bit)
 N_FEATURES = 62     # performance-counter feature bank size (paper Table I)
 _INF = jnp.float32(jnp.inf)
+# The phases of one `_masked_step` super-step, in its priority order: the
+# order of `SimState.fired` and `BatchTelemetry.phase_trips`, and the names
+# of the `jax.named_scope`s that tag each phase's ops in a profile.
+PHASES = ("completion", "kill", "deadline", "arrival", "decide", "advance")
 _NEG = jnp.float32(-jnp.inf)
 
 
@@ -240,6 +246,8 @@ class SimState(NamedTuple):
     recovery_us: jax.Array  # [] f32 sum over recovered tasks of
     #   (final finish - last kill time)
     n_recovered: jax.Array  # [] i32 killed tasks that eventually finished
+    fired: jax.Array        # [6] i32 phases of `PHASES` the last batched
+    #   super-step fired (1) or not (0); telemetry only, never a result
 
 
 class SimResult(NamedTuple):
@@ -989,6 +997,7 @@ def _init_state(wl: FlatWorkload, n_pes: int, pe_slow=None) -> SimState:
         n_kills=jnp.int32(0), n_retries=jnp.int32(0),
         reexec_us=jnp.float32(0.0), n_dropped_tasks=jnp.int32(0),
         recovery_us=jnp.float32(0.0), n_recovered=jnp.int32(0),
+        fired=jnp.zeros(len(PHASES), jnp.int32),
     )
 
 
@@ -1068,29 +1077,35 @@ def _masked_step(mode: int, params: SimParams, s: SimState,
     scheduling decision. The retired event *sequence* is exactly the
     switch path's, hence every result field stays bit-identical; only the
     grouping into loop iterations changes, which `ev` (events retired this
-    step, 0..4) accounts for so `n_iters` still equals the sequential
-    count. `run=False` makes the whole step a no-op, which is how the
+    step, 0..4, or 6 with faults) accounts for so `n_iters` still equals
+    the sequential count. `ev` is the sum of the phase flags, which the
+    step also leaves in `s.fired` (one 0/1 per entry of `PHASES`) for the
+    loop's phase counters. `run=False` makes the whole step a no-op, which
+    is how the
     batched driver freezes finished lanes. Used under vmap: a vmapped
     switch would execute all branches anyway and then select the *entire*
-    carry once per branch, which dominated the sweep cost.
+    carry once per branch, which dominated the sweep cost. Each phase's
+    ops sit under a `jax.named_scope` of its name (HLO metadata only).
     """
     I = wl.inst_arrival.shape[0]
     can_die, can_kill, has_deadline = fcaps if plan is not None \
         else flt.NO_CAPS
-    if plan is not None and can_die:
-        s = s._replace(pe_alive=flt.alive_at(plan, s.now))
-    # one two-level search serves completion detection, the completed task
-    # index, AND the advance target (the switch path derives all three
-    # from status/finish separately — same values, more passes)
-    fin_idx, fin_val = _next_completion(s)
-    c = run & (fin_val <= s.now)
-    s = _process_completion(params, wl, s, active=c, t=fin_idx, plan=plan,
-                            kmode=kmode)
+    with jax.named_scope("completion"):
+        if plan is not None and can_die:
+            s = s._replace(pe_alive=flt.alive_at(plan, s.now))
+        # one two-level search serves completion detection, the completed
+        # task index, AND the advance target (the switch path derives all
+        # three from status/finish separately — same values, more passes)
+        fin_idx, fin_val = _next_completion(s)
+        c = run & (fin_val <= s.now)
+        s = _process_completion(params, wl, s, active=c, t=fin_idx,
+                                plan=plan, kmode=kmode)
 
-    # a completion tie leaves another completion due: everything below
-    # must wait for the next iteration then, exactly as the switch would
-    next_fin = s.fin_seg.min()
-    no_c = ~(next_fin <= s.now)
+        # a completion tie leaves another completion due: everything
+        # below must wait for the next iteration then, exactly as the
+        # switch would
+        next_fin = s.fin_seg.min()
+        no_c = ~(next_fin <= s.now)
 
     # fault phases (priority: completion > kill > deadline > arrival).
     # Gates re-derive after each phase, mirroring the sequential 6-way
@@ -1103,36 +1118,43 @@ def _masked_step(mode: int, params: SimParams, s: SimState,
     k = dl = jnp.array(False)
     no_k = no_dl = jnp.array(True)
     if plan is not None and can_kill:
-        k_due, k_task, _ = _pending_kill(plan, s)
-        k = run & no_c & k_due
-        s = _process_kill(plan, params, wl, s, k_task, active=k, kmode=kmode)
-        no_k = ~_pending_kill(plan, s)[0]
+        with jax.named_scope("kill"):
+            k_due, k_task, _ = _pending_kill(plan, s)
+            k = run & no_c & k_due
+            s = _process_kill(plan, params, wl, s, k_task, active=k,
+                              kmode=kmode)
+            no_k = ~_pending_kill(plan, s)[0]
     if plan is not None and has_deadline:
-        dl_due, dl_inst = _pending_deadline(plan, wl, s)
-        dl = run & no_c & no_k & dl_due
-        s = _drop_instance(params, wl, s, dl_inst, active=dl)
-        no_dl = ~_pending_deadline(plan, wl, s)[0]
+        with jax.named_scope("deadline"):
+            dl_due, dl_inst = _pending_deadline(plan, wl, s)
+            dl = run & no_c & no_k & dl_due
+            s = _drop_instance(params, wl, s, dl_inst, active=dl)
+            no_dl = ~_pending_deadline(plan, wl, s)[0]
 
     def arr_due(st):
         return (st.arr_ptr < wl.n_insts) & (
             wl.inst_arrival[jnp.minimum(st.arr_ptr, I - 1)] <= st.now
         )
 
-    a = run & no_c & no_k & no_dl & arr_due(s)
-    s = _process_arrival(params, wl, s, active=a, plan=plan)
+    with jax.named_scope("arrival"):
+        a = run & no_c & no_k & no_dl & arr_due(s)
+        s = _process_arrival(params, wl, s, active=a, plan=plan)
 
-    # same-timestamp arrivals: the next one blocks the decide phase; an
-    # arrival can also arm an already-expired deadline (deadline_us ~ 0)
-    no_a = ~arr_due(s)
-    if plan is not None and has_deadline:
-        no_dl = ~_pending_deadline(plan, wl, s)[0]
-    can_decide = s.ready_cnt > 0
-    if plan is not None and can_die:
-        can_decide &= _can_schedule(mode, params, wl, s, tree,
-                                    rate_threshold, kmode)
-    d = run & no_c & no_k & no_dl & no_a & can_decide
-    s = _decide(mode, params, wl, s, tree, rate_threshold, active=d,
-                plan=plan, kmode=kmode)
+        # same-timestamp arrivals: the next one blocks the decide phase;
+        # an arrival can also arm an already-expired deadline
+        # (deadline_us ~ 0)
+        no_a = ~arr_due(s)
+        if plan is not None and has_deadline:
+            no_dl = ~_pending_deadline(plan, wl, s)[0]
+
+    with jax.named_scope("decide"):
+        can_decide = s.ready_cnt > 0
+        if plan is not None and can_die:
+            can_decide &= _can_schedule(mode, params, wl, s, tree,
+                                        rate_threshold, kmode)
+        d = run & no_c & no_k & no_dl & no_a & can_decide
+        s = _decide(mode, params, wl, s, tree, rate_threshold, active=d,
+                    plan=plan, kmode=kmode)
 
     # advance when nothing else can fire *after* this trip's phases: a
     # decide leaves finish > now (exec times are positive), so no
@@ -1140,36 +1162,41 @@ def _masked_step(mode: int, params: SimParams, s: SimState,
     # recompute the min. Queue emptiness is post-decide. After the final
     # completion the sequential cond exits without reaching do_advance,
     # hence the n_done guard.
-    if plan is None or not (can_kill or has_deadline):
-        # only a decide touched fin_seg this trip (no kills/drops traced)
-        next_fin = jnp.where(d, s.fin_seg.min(), next_fin)
-    else:
-        # kills / drops also touched fin_seg — recompute unconditionally
-        next_fin = s.fin_seg.min()
-    if plan is not None and can_die:
-        blocked = ~((s.ready_cnt > 0) & _can_schedule(
-            mode, params, wl, s, tree, rate_threshold, kmode))
-    else:
-        blocked = s.ready_cnt == 0
-    adv = (run & no_c & no_k & no_dl & no_a & blocked
-           & (s.n_done < wl.n_tasks))
-    next_arr = jnp.where(
-        s.arr_ptr < wl.n_insts,
-        wl.inst_arrival[jnp.minimum(s.arr_ptr, I - 1)], _INF,
-    )
-    nxt = jnp.minimum(next_fin, next_arr)
-    if plan is not None and (can_die or can_kill or has_deadline):
-        nxt = jnp.minimum(nxt, _next_wakeup(plan, wl, s, fcaps))
-    stuck = ~jnp.isfinite(nxt)
-    nxt = jnp.where(stuck, s.now, nxt)
-    s = s._replace(
-        now=jnp.where(adv, jnp.maximum(nxt, s.now), s.now),
-        stalled=s.stalled | (adv & stuck),
-    )
-    ev = (c.astype(jnp.int32) + k.astype(jnp.int32) + dl.astype(jnp.int32)
-          + a.astype(jnp.int32) + d.astype(jnp.int32)
-          + adv.astype(jnp.int32))
-    return s, ev
+    with jax.named_scope("advance"):
+        if plan is None or not (can_kill or has_deadline):
+            # only a decide touched fin_seg this trip (no kills/drops
+            # traced)
+            next_fin = jnp.where(d, s.fin_seg.min(), next_fin)
+        else:
+            # kills / drops also touched fin_seg — recompute
+            # unconditionally
+            next_fin = s.fin_seg.min()
+        if plan is not None and can_die:
+            blocked = ~((s.ready_cnt > 0) & _can_schedule(
+                mode, params, wl, s, tree, rate_threshold, kmode))
+        else:
+            blocked = s.ready_cnt == 0
+        adv = (run & no_c & no_k & no_dl & no_a & blocked
+               & (s.n_done < wl.n_tasks))
+        next_arr = jnp.where(
+            s.arr_ptr < wl.n_insts,
+            wl.inst_arrival[jnp.minimum(s.arr_ptr, I - 1)], _INF,
+        )
+        nxt = jnp.minimum(next_fin, next_arr)
+        if plan is not None and (can_die or can_kill or has_deadline):
+            nxt = jnp.minimum(nxt, _next_wakeup(plan, wl, s, fcaps))
+        stuck = ~jnp.isfinite(nxt)
+        nxt = jnp.where(stuck, s.now, nxt)
+        s = s._replace(
+            now=jnp.where(adv, jnp.maximum(nxt, s.now), s.now),
+            stalled=s.stalled | (adv & stuck),
+        )
+    flags = [f.astype(jnp.int32) for f in (c, k, dl, a, d, adv)]
+    # `ev` is summed here, not from `fired` in the loop: the TPU compiler
+    # lays the loop out differently when the events come from the stacked
+    # flags (PERF.md §6), so the flags only feed the counters
+    ev = flags[0] + flags[1] + flags[2] + flags[3] + flags[4] + flags[5]
+    return s._replace(fired=jnp.stack(flags)), ev
 
 
 def _finalize(wl: FlatWorkload, s: SimState, iters: jax.Array,
@@ -1393,6 +1420,13 @@ class BatchTelemetry(NamedTuple):
     """
     loop_trips: jax.Array    # [S] while-loop trips of the lane's shard
     active_trips: jax.Array  # [S] trips on which the lane was still live
+    # Per shard, broadcast to each of its lanes like `loop_trips`:
+    phase_trips: jax.Array       # [S, 6] trips on which any lane of the
+    #   shard fired each phase of `PHASES`
+    fault_eval_trips: jax.Array  # [S] trips that evaluated the kill /
+    #   deadline bodies (every trip where `fcaps` compiled them in)
+    fault_fire_trips: jax.Array  # [S] trips on which any lane fired a
+    #   kill or a deadline drop
 
 
 def _simulate_batch_impl(mode, params, wls, tree, rate_threshold, plan,
@@ -1427,21 +1461,30 @@ def _simulate_batch_impl(mode, params, wls, tree, rate_threshold, plan,
     def running(s, it):
         return (s.n_done < wls.n_tasks) & ~s.stalled & (it < max_iters)
 
+    _, can_kill, has_deadline = fcaps if plan is not None else flt.NO_CAPS
+    fault_bodies = jnp.int32(can_kill or has_deadline)
+
     def cond(carry):
-        s, it, act, trips = carry
+        s, it = carry[:2]
         return jnp.any(running(s, it))
 
     def body(carry):
-        s, it, act, trips = carry
+        s, it, act, trips, phase, f_eval, f_fire = carry
         run = running(s, it)
         s, ev = step(s, wls, tree, rate_threshold, plan, run)
         # it counts retired *events*, matching the sequential n_iters
         # (a super-step can retire up to 4, or 6 with faults). A lane
         # within a few of max_iters may overshoot the cap by a couple of
         # events; max_iters is a pathology backstop, so the slack is
-        # irrelevant in practice. `act`/`trips` are occupancy telemetry
-        # only — they feed BatchTelemetry, never the result.
-        return (s, it + ev, act + run.astype(jnp.int32), trips + 1)
+        # irrelevant in practice. Everything after `it` is telemetry
+        # only — it feeds BatchTelemetry, never the result. The fault
+        # bodies run inside `step` on every trip while `fcaps` traces
+        # them, so `f_eval` counts here.
+        any_fired = jnp.any(s.fired > 0, axis=0)
+        return (s, it + ev, act + run.astype(jnp.int32),
+                trips + 1, phase + any_fired.astype(jnp.int32),
+                f_eval + fault_bodies,
+                f_fire + (any_fired[1] | any_fired[2]).astype(jnp.int32))
 
     if plan is None:
         pe_slow, slow_axis = None, None
@@ -1450,25 +1493,97 @@ def _simulate_batch_impl(mode, params, wls, tree, rate_threshold, plan,
         slow_axis = 0 if pe_slow.ndim == 2 else None
     s0 = jax.vmap(_init_state, in_axes=(0, None, slow_axis))(
         wls, n_pes, pe_slow)
-    s, iters, act, trips = jax.lax.while_loop(
+    zero = jnp.int32(0)
+    s, iters, act, trips, phase, f_eval, f_fire = jax.lax.while_loop(
         cond, body,
-        (s0, jnp.zeros(S, jnp.int32), jnp.zeros(S, jnp.int32),
-         jnp.int32(0)))
+        (s0, jnp.zeros(S, jnp.int32), jnp.zeros(S, jnp.int32), zero,
+         jnp.zeros(len(PHASES), jnp.int32), zero, zero))
     # max_iters is [S] when a batched plan varied it per lane, scalar
     # otherwise; either way every lane sees the same cap as the sequential
     # path, so `stall_reason` stays bit-exact between the two engines
     mi = jnp.asarray(max_iters, jnp.int32)
     mi_axis = 0 if mi.ndim == 1 else None
     res = jax.vmap(_finalize, in_axes=(0, 0, 0, mi_axis))(wls, s, iters, mi)
-    # trips broadcasts to [S] so sharded runs report each lane against its
-    # own shard's loop (sum over lanes == lane-iterations allocated)
-    tel = BatchTelemetry(loop_trips=jnp.full((S,), trips, jnp.int32),
-                         active_trips=act)
+    # shard counts broadcast to [S] so sharded runs report each lane
+    # against its own shard's loop (sum over lanes of loop_trips ==
+    # lane-iterations allocated)
+    tel = BatchTelemetry(
+        loop_trips=jnp.full((S,), trips, jnp.int32), active_trips=act,
+        phase_trips=jnp.broadcast_to(phase, (S, len(PHASES))),
+        fault_eval_trips=jnp.full((S,), f_eval, jnp.int32),
+        fault_fire_trips=jnp.full((S,), f_fire, jnp.int32))
     return res, tel
 
 
 _simulate_batch = jax.jit(_simulate_batch_impl,
                           static_argnums=(0, 6, 7, 8, 9, 10, 11))
+
+
+class _Spans:
+    """Host spans of one engine call: the call itself (`root`, e.g.
+    `run_batch`) and its steps (`<root>.<step>`).
+
+    Every span opens a profiler annotation `repro.<name>`, so xprof and
+    Perfetto show it on the device trace's clock, above the device ops it
+    caused. When the caller passed a telemetry sink, the span is also kept
+    as `{"name", "parent", "start_ns", "end_ns"}` on
+    `time.perf_counter_ns()`: the call's own spans go on the first record
+    the call appends (`attach`), a chunk's on that chunk's record (`into`).
+    """
+
+    def __init__(self, root: str, telemetry: list | None):
+        self.root = root
+        self.telemetry = telemetry
+        self.first = len(telemetry) if telemetry is not None else 0
+        self.call = [] if telemetry is not None else None
+
+    @property
+    def on(self) -> bool:
+        return self.call is not None
+
+    @contextlib.contextmanager
+    def __call__(self, step: str | None = None, into: list | None = None):
+        name = self.root if step is None else f"{self.root}.{step}"
+        with jax.profiler.TraceAnnotation(f"repro.{name}"):
+            start = time.perf_counter_ns()
+            try:
+                yield
+            finally:
+                if self.on:
+                    (self.call if into is None else into).append({
+                        "name": name,
+                        "parent": None if step is None else self.root,
+                        "start_ns": start, "end_ns": time.perf_counter_ns()})
+
+    def attach(self) -> None:
+        """Put the call's own spans on the first record it appended."""
+        if self.on and len(self.telemetry) > self.first:
+            rec = self.telemetry[self.first]
+            rec["spans"] = sorted(self.call + rec["spans"],
+                                  key=lambda sp: sp["start_ns"])
+
+
+def _engine_call(spans: _Spans, mode: int, params: SimParams,
+                 wls: FlatWorkload, tree: DTree, rate_threshold: jax.Array,
+                 plan, step_budget: int | None, kernels: str | None):
+    """One unsharded engine call under a `dispatch` span, with its
+    telemetry record (and `fetch` span) when a sink is on."""
+    own = [] if spans.on else None
+    with spans("dispatch", own):
+        tree_axis = 0 if tree.feat.ndim == 2 else None
+        thr_axis = 0 if getattr(rate_threshold, "ndim", 0) >= 1 else None
+        plan_axis = (0 if plan is not None and plan.pe_fail_at.ndim == 2
+                     else None)
+        fcaps = flt.plan_capabilities(plan) if plan is not None \
+            else flt.NO_CAPS
+        res, tel = _simulate_batch(mode, params, wls, tree, rate_threshold,
+                                   plan, tree_axis, thr_axis, plan_axis,
+                                   step_budget, _kops.kernel_mode(kernels),
+                                   fcaps)
+    if spans.on:
+        spans.telemetry.append(_telemetry_record(
+            res, tel, len(tel.loop_trips.devices()), spans, own))
+    return res
 
 
 def simulate_batch(mode: int, params: SimParams, wls: FlatWorkload,
@@ -1490,38 +1605,47 @@ def simulate_batch(mode: int, params: SimParams, wls: FlatWorkload,
 
     `kernels` overrides the `REPRO_SIM_KERNELS` knob (resolved here, at
     call time, so env flips dispatch correctly). When `telemetry` is a
-    list, a per-call occupancy record (lane-iterations allocated vs.
-    retired) is appended to it.
+    list, a per-call record (`_telemetry_record`: occupancy, phase
+    counters, and the `simulate_batch` spans) is appended to it.
     """
-    tree_axis = 0 if tree.feat.ndim == 2 else None
-    thr_axis = 0 if getattr(rate_threshold, "ndim", 0) >= 1 else None
-    plan_axis = 0 if plan is not None and plan.pe_fail_at.ndim == 2 else None
-    fcaps = flt.plan_capabilities(plan) if plan is not None else flt.NO_CAPS
-    res, tel = _simulate_batch(mode, params, wls, tree, rate_threshold,
-                               plan, tree_axis, thr_axis, plan_axis,
-                               step_budget, _kops.kernel_mode(kernels),
-                               fcaps)
-    if telemetry is not None:
-        telemetry.append(_telemetry_record(res, tel,
-                                           len(tel.loop_trips.devices())))
+    spans = _Spans("simulate_batch", telemetry)
+    with spans():
+        res = _engine_call(spans, mode, params, wls, tree, rate_threshold,
+                           plan, step_budget, kernels)
+    spans.attach()
     return res
 
 
-def _telemetry_record(res: SimResult, tel: BatchTelemetry,
-                      devices: int) -> dict:
-    """Host-side occupancy record for one engine call (blocks on `tel`);
-    `devices` is how many devices the call's lanes ran on."""
-    loop = np.asarray(jax.device_get(tel.loop_trips))
-    act = np.asarray(jax.device_get(tel.active_trips))
-    events = np.asarray(jax.device_get(res.n_iters))
+def _telemetry_record(res: SimResult, tel: BatchTelemetry, devices: int,
+                      spans: _Spans, own: list) -> dict:
+    """Host-side record of one engine call (blocks on `tel`, under a
+    `fetch` span); `devices` is how many devices the call's lanes ran on,
+    `own` the spans of this call's chunk (the fetch span joins them).
+
+    Counters of the loop's shards (`phase_trips`, `fault_eval_trips`,
+    `fault_fire_trips`) are summed over shards, not lanes.
+    """
+    with spans("fetch", own):
+        loop, act, events, phase, f_eval, f_fire = jax.device_get((
+            tel.loop_trips, tel.active_trips, res.n_iters,
+            tel.phase_trips, tel.fault_eval_trips, tel.fault_fire_trips))
+    loop = np.asarray(loop)
+    act = np.asarray(act)
     allocated = int(loop.sum())
+    # each shard's counts sit on every one of its lanes; shards are equal
+    first_lanes = slice(None, None, loop.shape[0] // devices)
+    phase = np.asarray(phase)[first_lanes].sum(axis=0)
     return {
         "lanes": int(loop.shape[0]),
         "devices": devices,
         "lane_trips": allocated,            # sum over lanes of shard trips
         "active_trips": int(act.sum()),     # trips with the lane still live
-        "events": int(events.sum()),        # retired simulator events
+        "events": int(np.asarray(events).sum()),  # retired simulator events
         "occupancy": float(act.sum() / allocated) if allocated else 1.0,
+        "phase_trips": {n: int(v) for n, v in zip(PHASES, phase)},
+        "fault_eval_trips": int(np.asarray(f_eval)[first_lanes].sum()),
+        "fault_fire_trips": int(np.asarray(f_fire)[first_lanes].sum()),
+        "spans": own,
     }
 
 
@@ -1534,8 +1658,8 @@ def result_at(res: SimResult, i: int) -> SimResult:
     return jax.tree_util.tree_map(lambda x: x[i], res)
 
 
-def _prep_plan(plan, params: SimParams, batched: bool):
-    """Validate a user-supplied FaultPlan and move it to device arrays."""
+def _check_plan(plan, params: SimParams, batched: bool):
+    """Validate a user-supplied FaultPlan (host side)."""
     if plan is None:
         return None
     plan = flt.validate_plan(plan, n_pes=params.pe_cluster.shape[0],
@@ -1543,7 +1667,12 @@ def _prep_plan(plan, params: SimParams, batched: bool):
     if not batched and flt.is_batched(plan):
         raise ValueError("run: got a batched FaultPlan (leading scenario "
                          "axis); use run_batch for plan sweeps")
-    return flt.FaultPlan(*[jnp.asarray(x) for x in plan])
+    return plan
+
+
+def _plan_to_device(plan):
+    return None if plan is None else flt.FaultPlan(
+        *[jnp.asarray(x) for x in plan])
 
 
 def _resolve_devices(devices) -> tuple:
@@ -1621,7 +1750,7 @@ def run(mode: int, wl: FlatWorkload, params: SimParams | None = None,
     (decision-kernel dispatch, resolved at call time)."""
     params = params or make_params()
     tree = tree or always_fast_tree()
-    plan = _prep_plan(plan, params, batched=False)
+    plan = _plan_to_device(_check_plan(plan, params, batched=False))
     fcaps = flt.plan_capabilities(plan) if plan is not None else flt.NO_CAPS
     return simulate(mode, params, to_device(wl), tree,
                     jnp.float32(rate_threshold), plan, step_budget,
@@ -1661,45 +1790,63 @@ def run_batch(mode: int, wls, params: SimParams | None = None,
 
     `kernels` overrides the `REPRO_SIM_KERNELS` decision-kernel knob
     (resolved here at call time). When `telemetry` is a list, one
-    occupancy record per chunk (lane-iterations allocated vs. retired) is
-    appended to it — out-of-band so results stay bit-exact across chunk
-    compositions.
+    record per chunk (`_telemetry_record`: lane-iterations allocated vs.
+    retired, phase counters, spans) is appended to it — out-of-band so
+    results stay bit-exact across chunk compositions. The call's own
+    spans (`run_batch`, `.stack`, `.to_device`, and the one `.fetch` of a
+    multi-chunk sweep) go on its first record; each chunk's `.dispatch`
+    and `.fetch` on its own.
     """
-    from repro.core.workloads import stack_workloads
+    spans = _Spans("run_batch", telemetry)
+    with spans():
+        res = _run_batch(spans, mode, wls, params, tree, rate_threshold,
+                         batch_size, plan, devices, step_budget, kernels)
+    spans.attach()
+    return res
 
-    if batch_size is not None and batch_size <= 0:
-        raise ValueError(f"batch_size must be positive, got {batch_size}")
-    params = params or make_params()
-    tree = tree or always_fast_tree()
-    plan = _prep_plan(plan, params, batched=True)
-    if isinstance(wls, FlatWorkload):
-        stacked = wls
-    else:
-        stacked = stack_workloads(wls)
-    stacked = to_device(stacked)
-    n = stacked.task_type.shape[0]
-    plan_b = plan is not None and flt.is_batched(plan)
-    if plan_b and plan.pe_fail_at.shape[0] != n:
-        raise ValueError(
-            f"run_batch: batched plan has {plan.pe_fail_at.shape[0]} "
-            f"scenarios but the workload has {n}")
-    if not isinstance(rate_threshold, jax.Array):
-        rate_threshold = jnp.float32(rate_threshold)
+
+def _run_batch(spans: _Spans, mode, wls, params, tree, rate_threshold,
+               batch_size, plan, devices, step_budget, kernels):
+    """`run_batch`'s body, one `spans` step at a time."""
+    with spans("stack"):
+        if batch_size is not None and batch_size <= 0:
+            raise ValueError(
+                f"batch_size must be positive, got {batch_size}")
+        params = params or make_params()
+        tree = tree or always_fast_tree()
+        plan = _check_plan(plan, params, batched=True)
+        if isinstance(wls, FlatWorkload):
+            stacked = wls
+        else:
+            stacked = stack_workloads(wls)
+        n = stacked.task_type.shape[0]
+        plan_b = plan is not None and flt.is_batched(plan)
+        if plan_b and np.shape(plan.pe_fail_at)[0] != n:
+            raise ValueError(
+                f"run_batch: batched plan has {np.shape(plan.pe_fail_at)[0]}"
+                f" scenarios but the workload has {n}")
+    with spans("to_device"):
+        stacked = to_device(stacked)
+        plan = _plan_to_device(plan)
+        if not isinstance(rate_threshold, jax.Array):
+            rate_threshold = jnp.float32(rate_threshold)
+        if spans.on:
+            # time the copy itself, not its enqueue
+            jax.block_until_ready((stacked, plan, rate_threshold))
 
     devs = _resolve_devices(devices)
     D = len(devs)
-    kern = _kops.kernel_mode(kernels)
-    fcaps = flt.plan_capabilities(plan) if plan is not None else flt.NO_CAPS
     # fixed chunk shape: user size clamped to n, rounded up to a device
     # multiple so every shard is equal-sized
     B = n if batch_size is None else min(batch_size, n)
     B = -(-B // D) * D
     if D == 1 and B >= n:
         # single device, single chunk: the plain vmapped engine
-        return simulate_batch(mode, params, stacked, tree, rate_threshold,
-                              plan, step_budget=step_budget, kernels=kern,
-                              telemetry=telemetry)
+        return _engine_call(spans, mode, params, stacked, tree,
+                            rate_threshold, plan, step_budget, kernels)
 
+    kern = _kops.kernel_mode(kernels)
+    fcaps = flt.plan_capabilities(plan) if plan is not None else flt.NO_CAPS
     tree_b = tree.feat.ndim == 2
     thr_b = rate_threshold.ndim >= 1
     if D > 1:
@@ -1719,7 +1866,7 @@ def run_batch(mode: int, wls, params: SimParams | None = None,
     n_pad = -(-n // B) * B
     # pad lanes replay the last real scenario; their results are dropped
     pad_idx = np.minimum(np.arange(n_pad), n - 1)
-    chunks = []
+    chunks, own = [], []
     for lo in range(0, n_pad, B):
         ids = pad_idx[lo:lo + B]
         if ids[-1] == lo + B - 1:          # fully-real chunk: cheap slice
@@ -1728,17 +1875,23 @@ def run_batch(mode: int, wls, params: SimParams | None = None,
         else:                              # final chunk: padded gather
             def sl(x, ids=ids):
                 return x[ids]
-        part = jax.tree_util.tree_map(sl, stacked)
-        t = jax.tree_util.tree_map(sl, tree) if tree_b else tree
-        rt = sl(rate_threshold) if thr_b else rate_threshold
-        pl = jax.tree_util.tree_map(sl, plan) if plan_b else plan
-        chunks.append(dispatch(params, part, t, rt, pl))
+        own.append([] if spans.on else None)
+        with spans("dispatch", own[-1]):
+            part = jax.tree_util.tree_map(sl, stacked)
+            t = jax.tree_util.tree_map(sl, tree) if tree_b else tree
+            rt = sl(rate_threshold) if thr_b else rate_threshold
+            pl = jax.tree_util.tree_map(sl, plan) if plan_b else plan
+            chunks.append(dispatch(params, part, t, rt, pl))
     n_devs = [len(tel_c.loop_trips.devices()) for _, tel_c in chunks]
-    # one blocking fetch for the whole sweep (dispatches above are async)
-    chunks = jax.device_get(chunks)
-    if telemetry is not None:
-        for (res_c, tel_c), d in zip(chunks, n_devs):
-            telemetry.append(_telemetry_record(res_c, tel_c, d))
-    return jax.tree_util.tree_map(
-        lambda *xs: np.concatenate(xs, axis=0)[:n],
-        *[res_c for res_c, _ in chunks])
+    with spans("fetch"):
+        # one blocking fetch for the whole sweep (dispatches above are
+        # async)
+        chunks = jax.device_get(chunks)
+        res = jax.tree_util.tree_map(
+            lambda *xs: np.concatenate(xs, axis=0)[:n],
+            *[res_c for res_c, _ in chunks])
+    if spans.on:
+        for (res_c, tel_c), d, o in zip(chunks, n_devs, own):
+            spans.telemetry.append(_telemetry_record(res_c, tel_c, d,
+                                                     spans, o))
+    return res

@@ -24,8 +24,8 @@ case), and the push-time rows are bitwise identical to the inline
 [K, MP, P] contribution max.
 
 `DISPATCH_COUNT` tallies which backend each decision primitive traced
-through (trace-time, mirroring `sim.TRACE_COUNT`) — surfaced by
-`benchmarks/run.py --json` so sweeps record which path actually ran.
+through (trace-time, mirroring `sim.TRACE_COUNT`); the tests and
+`chip_smoke.py` read it to prove which path actually ran.
 """
 from __future__ import annotations
 

@@ -179,7 +179,9 @@ def test_run_kernels_pallas_interpret_matches(mode):
 
 def test_run_batch_kernels_telemetry():
     """`telemetry=[]` collects one record per dispatch: allocated vs
-    active lane-trips, retired events, and an occupancy in (0, 1]."""
+    active lane-trips, retired events, an occupancy in (0, 1], the loop's
+    phase counters and the chunk's spans (tests/test_telemetry_spans.py
+    holds the spans and counters to their meaning)."""
     tel = []
     r = sim.run_batch(sim.MODE_ETF, WLS, PARAMS, batch_size=2, devices=1,
                       kernels="xla", telemetry=tel)
@@ -190,6 +192,11 @@ def test_run_batch_kernels_telemetry():
         assert t["devices"] == 1
         assert 0 < t["active_trips"] <= t["lane_trips"]
         assert 0 < t["occupancy"] <= 1.0
+        trips = t["lane_trips"] // t["lanes"]
+        assert 0 < t["phase_trips"]["decide"] <= trips
+        assert t["fault_eval_trips"] == t["fault_fire_trips"] == 0
+        names = {sp["name"] for sp in t["spans"]}
+        assert {"run_batch.dispatch", "run_batch.fetch"} <= names
 
 
 def test_kernels_no_retrace_across_two_sweeps():
