@@ -775,11 +775,16 @@ def _pending_kill(plan, s: SimState):
     transient glitch at tau with `assign_t < tau <= now`. Ties break to
     the lowest task id (argmin), matching `ref_sim`."""
     taus = flt.kill_times(plan)                         # [P, K]
-    t_taus = taus[jnp.maximum(s.pe_of, 0)]              # [T, K]
+    # each task's row of `taus`, selected from the P rows by a one-hot:
+    # under `vmap` a gather `taus[pe_of]` walks lanes x tasks serially
+    on_pe = (jnp.arange(taus.shape[0])[:, None]
+             == jnp.maximum(s.pe_of, 0)[None, :])      # [P, T]
+    t_taus = jnp.where(on_pe[None], taus.T[:, :, None],
+                       _NEG).max(axis=1)                # [K, T]
     running = s.status == 3
-    due = (running[:, None] & (s.assign_t[:, None] < t_taus)
-           & (t_taus <= s.now))                         # [T, K]
-    tau_t = jnp.where(due, t_taus, _INF).min(axis=1)    # [T]
+    due = (running[None, :] & (s.assign_t[None, :] < t_taus)
+           & (t_taus <= s.now))                         # [K, T]
+    tau_t = jnp.where(due, t_taus, _INF).min(axis=0)    # [T]
     t = jnp.argmin(tau_t).astype(jnp.int32)
     return due.any(), t, tau_t[t]
 
@@ -790,10 +795,17 @@ def _drop_instance(p: SimParams, wl: FlatWorkload, s: SimState,
     retry exhaustion). Running work rolls back its unexecuted tail
     (busy time + energy), queued tasks are purged from the FIFO with
     order preserved, and every victim retires as status 5 so the
-    termination count (`n_done`) still converges."""
+    termination count (`n_done`) still converges.
+
+    Every task-length write is a dense masked op over the whole row: a
+    `where` on the victim mask for the per-task fields, and a [P, T]
+    PE-by-task one-hot reduced over tasks for the per-PE fields. Under
+    `vmap` a gated scatter would be one serial scatter over lanes x tasks
+    updates on every trip, whether or not a lane drops. `pe_busy` takes
+    the running victims' tails off one a PE a pass, in task order, so it
+    rounds as subtracting them one by one does."""
     T = s.status.shape[0]
     P = s.pe_free.shape[0]
-    ar = jnp.arange(T)
     inst = jnp.maximum(inst, 0)
     victim = (wl.inst_id == inst) & wl.task_valid & (s.status < 4)
     if active is not None:
@@ -808,23 +820,34 @@ def _drop_instance(p: SimParams, wl: FlatWorkload, s: SimState,
     executed = jnp.where(runn, jnp.clip(s.now - s.start, 0.0, exec_total),
                          0.0)
     unexec = exec_total - executed
-    pe_ix = jnp.where(runn, pe, P)
-    pe_busy = s.pe_busy.at[pe_ix].add(-unexec, mode="drop")
+    on_pe = jnp.arange(P)[:, None] == pe[None, :]           # [P, T]
+    lost = on_pe & runn[None, :]
+
+    # a sum of several tails on one PE could round differently
+    def roll_back(c):
+        left, busy = c
+        rows = on_pe & left[None, :]
+        first = rows & (jnp.arange(T)[None, :]
+                        == jnp.argmax(rows, axis=1)[:, None])
+        return (left & ~first.any(axis=0),
+                busy - jnp.where(first, unexec[None, :], 0.0).sum(axis=1))
+
+    _, pe_busy = jax.lax.while_loop(lambda c: c[0].any(), roll_back,
+                                    (runn, s.pe_busy))
     e_back = (jnp.where(runn, unexec * p.pe_power[pe], 0.0)).sum()
     # PEs that lost a victim rebuild pe_free from surviving assignments;
     # untouched PEs keep their exact value
-    pe_hit = jnp.zeros(P, bool).at[pe_ix].set(True, mode="drop")
     surv = (s.status == 3) & ~victim
-    surv_fin = jnp.full(P, _NEG).at[jnp.where(surv, pe, P)].max(
-        s.finish, mode="drop")
-    pe_free = jnp.where(pe_hit, jnp.maximum(surv_fin, s.now), s.pe_free)
+    surv_fin = jnp.where(on_pe & surv[None, :], s.finish[None, :],
+                         _NEG).max(axis=1)
+    pe_free = jnp.where(lost.any(axis=1), jnp.maximum(surv_fin, s.now),
+                        s.pe_free)
 
-    vix = jnp.where(victim, ar, T)
-    status = s.status.at[vix].set(5, mode="drop")
+    status = jnp.where(victim, jnp.int8(5), s.status)
     # -inf keeps dropped tasks out of the makespan / inst_fin maxima
-    finish = s.finish.at[vix].set(_NEG, mode="drop")
-    fin_run = s.fin_run.at[jnp.where(runn, ar, s.fin_run.shape[0])].set(
-        _INF, mode="drop")
+    finish = jnp.where(victim, _NEG, s.finish)
+    fin_run = jnp.where(jnp.pad(runn, (0, s.fin_run.shape[0] - T)), _INF,
+                        s.fin_run)
     # victims may span many segments: full fin_seg rebuild (exactly the
     # invariant value, so a no-op drop stays bit-identical)
     fin_seg = fin_run.reshape(-1, SEG).min(axis=1)
@@ -839,8 +862,8 @@ def _drop_instance(p: SimParams, wl: FlatWorkload, s: SimState,
 
     return s._replace(
         status=status, finish=finish, fin_run=fin_run, fin_seg=fin_seg,
-        start=s.start.at[vix].set(_INF, mode="drop"),
-        assign_t=s.assign_t.at[vix].set(_INF, mode="drop"),
+        start=jnp.where(victim, _INF, s.start),
+        assign_t=jnp.where(victim, _INF, s.assign_t),
         pe_busy=pe_busy, pe_free=pe_free,
         task_energy=_gate(active, s.task_energy - e_back, s.task_energy),
         n_running=s.n_running - runn.sum().astype(jnp.int32),
@@ -1112,9 +1135,12 @@ def _masked_step(mode: int, params: SimParams, s: SimState,
     # switch: a second due kill / deadline blocks everything later. A
     # phase the plan's static capabilities rule out (see
     # `faults.plan_capabilities`) is skipped at trace time — its `due`
-    # predicate would be identically False, so the skip is exact, and
-    # the per-trip cost of the kill/drop machinery (FIFO purges, fin_seg
-    # rebuilds, re-push) vanishes for plans that can never fire it.
+    # predicate would be identically False, so the skip is exact. A
+    # traced phase runs on every trip of every lane, fired or not, so the
+    # kill/drop machinery (due-checks, FIFO purges, fin_seg rebuilds,
+    # re-push) is written as dense masked passes over the task and PE
+    # rows: under `vmap` a gated scatter or gather over a task row would
+    # walk lanes x tasks serially on every trip.
     k = dl = jnp.array(False)
     no_k = no_dl = jnp.array(True)
     if plan is not None and can_kill:

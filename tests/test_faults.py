@@ -409,3 +409,186 @@ def test_gated_deadline_phase_bit_exact_when_slack():
     r_full = sim.run(sim.MODE_ETF, WL, PARAMS, plan=slack)
     assert int(r_full.n_dropped_jobs) == 0
     _assert_results_equal(r_gated, r_full)
+
+
+# ---------------------------------------------------------------------------
+# `_drop_instance`'s dense masked writes against the gated-scatter form
+# ---------------------------------------------------------------------------
+def _scatter_drop(p, wl, s, inst, active=None):
+    """`_drop_instance` written with gated scatters (test-only oracle):
+    every task-length write is `x.at[where(mask, idx, OOB)].op(v,
+    mode="drop")`, and the per-PE fields scatter tasks onto their PEs."""
+    import jax.numpy as jnp
+    T = s.status.shape[0]
+    P = s.pe_free.shape[0]
+    ar = jnp.arange(T)
+    inst = jnp.maximum(inst, 0)
+    victim = (wl.inst_id == inst) & wl.task_valid & (s.status < 4)
+    if active is not None:
+        victim &= active
+    n_v = victim.sum().astype(jnp.int32)
+    runn = victim & (s.status == 3)
+    pe = jnp.maximum(s.pe_of, 0)
+    exec_total = jnp.where(runn, s.finish - s.start, 0.0)
+    executed = jnp.where(runn, jnp.clip(s.now - s.start, 0.0, exec_total),
+                         0.0)
+    unexec = exec_total - executed
+    pe_ix = jnp.where(runn, pe, P)
+    pe_busy = s.pe_busy.at[pe_ix].add(-unexec, mode="drop")
+    e_back = (jnp.where(runn, unexec * p.pe_power[pe], 0.0)).sum()
+    pe_hit = jnp.zeros(P, bool).at[pe_ix].set(True, mode="drop")
+    surv = (s.status == 3) & ~victim
+    surv_fin = jnp.full(P, sim._NEG).at[jnp.where(surv, pe, P)].max(
+        s.finish, mode="drop")
+    pe_free = jnp.where(pe_hit, jnp.maximum(surv_fin, s.now), s.pe_free)
+    vix = jnp.where(victim, ar, T)
+    fin_run = s.fin_run.at[jnp.where(runn, ar, s.fin_run.shape[0])].set(
+        sim._INF, mode="drop")
+    in_q = s.ready_ids >= 0
+    is_v = jnp.where(in_q, victim[jnp.maximum(s.ready_ids, 0)], False)
+    keep = in_q & ~is_v
+    perm = jnp.argsort((~keep).astype(jnp.int32))
+    new_cnt = keep.sum().astype(jnp.int32)
+    ids_p = jnp.where(jnp.arange(sim.R_MAX) < new_cnt, s.ready_ids[perm], -1)
+    gate = sim._gate
+    return s._replace(
+        status=s.status.at[vix].set(5, mode="drop"),
+        finish=s.finish.at[vix].set(sim._NEG, mode="drop"),
+        fin_run=fin_run, fin_seg=fin_run.reshape(-1, sim.SEG).min(axis=1),
+        start=s.start.at[vix].set(sim._INF, mode="drop"),
+        assign_t=s.assign_t.at[vix].set(sim._INF, mode="drop"),
+        pe_busy=pe_busy, pe_free=pe_free,
+        task_energy=gate(active, s.task_energy - e_back, s.task_energy),
+        n_running=s.n_running - runn.sum().astype(jnp.int32),
+        n_done=s.n_done + n_v,
+        n_dropped_tasks=s.n_dropped_tasks + n_v,
+        ready_ids=gate(active, ids_p, s.ready_ids),
+        ready_avail=gate(active, s.ready_avail[perm], s.ready_avail),
+        ready_exec=gate(active, s.ready_exec[perm], s.ready_exec),
+        ready_cnt=gate(active, new_cnt, s.ready_cnt),
+        inst_rem=sim._gset(active, s.inst_rem, inst, 0),
+        job_dropped=sim._gset(active, s.job_dropped, inst, True),
+    )
+
+
+def _random_drop_state(seed: int, case: str):
+    """(state, inst): a mid-run state with the victim instance `inst`
+    shaped by `case` — `stacked` puts its running tasks two or three to
+    a PE, `done` leaves most of it finished or dropped already, `fifo`
+    queues some of it in the ready FIFO, `mixed` draws everything."""
+    import jax
+    import jax.numpy as jnp
+    rng = np.random.default_rng(seed)
+    T, P = WL.task_type.shape[0], soc.N_PES
+    valid = np.asarray(WL.task_valid)
+    inst_id = np.asarray(WL.inst_id)
+    inst = int(rng.integers(int(WL.n_insts)))
+    mine = np.where(valid & (inst_id == inst))[0]
+    status = rng.choice(np.array([0, 2, 3, 4, 5], np.int8), size=T,
+                        p=[0.2, 0.2, 0.3, 0.2, 0.1])
+    if case == "stacked":
+        status[mine] = 3
+        status[mine[rng.random(mine.size) < 0.2]] = 4
+    elif case == "done":
+        status[mine] = rng.choice(np.array([4, 5], np.int8), mine.size)
+        status[mine[0]] = 3
+    elif case == "fifo":
+        status[mine] = rng.choice(np.array([2, 3], np.int8), mine.size)
+    status[~valid] = 0
+    sched = (status == 3) | (status == 4)
+    pe_of = np.where(sched, rng.integers(P, size=T), -1).astype(np.int32)
+    if case == "stacked":
+        run_mine = mine[status[mine] == 3]
+        pe_of[run_mine] = rng.choice(rng.choice(P, 3, replace=False),
+                                     run_mine.size)
+    now = np.float32(rng.uniform(5.0, 50.0))
+    start = np.where(sched, rng.uniform(0.0, 60.0, T), np.inf)
+    finish = np.where(sched, start + rng.uniform(0.05, 5.0, T), np.inf)
+    finish = np.where(status == 5, -np.inf, finish).astype(np.float32)
+    start = start.astype(np.float32)
+    s0 = sim._init_state(WL, P)
+    fin_run = np.full(s0.fin_run.shape, np.inf, np.float32)
+    fin_run[:T] = np.where(status == 3, finish, np.inf)
+    queued = np.where(status == 2)[0]
+    rng.shuffle(queued)
+    queued = queued[:sim.R_MAX]
+    ready_ids = np.full(sim.R_MAX, -1, np.int32)
+    ready_ids[:queued.size] = queued
+    s = s0._replace(
+        now=now, status=status, pe_of=pe_of, start=start, finish=finish,
+        fin_run=fin_run, fin_seg=fin_run.reshape(-1, sim.SEG).min(axis=1),
+        assign_t=np.where(status == 3, start - 0.5, np.inf).astype(
+            np.float32),
+        pe_busy=rng.uniform(0.0, 100.0, P).astype(np.float32),
+        pe_free=rng.uniform(0.0, 60.0, P).astype(np.float32),
+        ready_ids=ready_ids, ready_cnt=np.int32(queued.size),
+        ready_avail=rng.uniform(0.0, 60.0, (sim.R_MAX, P)).astype(
+            np.float32),
+        ready_exec=rng.uniform(0.05, 5.0, (sim.R_MAX, P)).astype(
+            np.float32),
+        n_running=np.int32((status == 3).sum()),
+        n_done=np.int32((status >= 4).sum()),
+        task_energy=np.float32(rng.uniform(100.0, 200.0)),
+        inst_rem=rng.integers(0, 20, np.asarray(WL.inst_arrival).shape[0]
+                              ).astype(np.int32))
+    return jax.tree_util.tree_map(jnp.asarray, s), np.int32(inst)
+
+
+def _assert_drop_matches(dense, oracle):
+    for name in sim.SimState._fields:
+        a, b = np.asarray(getattr(dense, name)), np.asarray(
+            getattr(oracle, name))
+        assert np.array_equal(a, b, equal_nan=True), name
+
+
+def _lost_per_pe(s, inst):
+    """[P] running tasks of `inst` each PE loses to an active drop."""
+    victim = (np.asarray(WL.inst_id) == inst) & np.asarray(WL.task_valid) \
+        & (np.asarray(s.status) < 4)
+    runn = victim & (np.asarray(s.status) == 3)
+    return np.bincount(np.asarray(s.pe_of)[runn], minlength=soc.N_PES)
+
+
+@pytest.mark.parametrize("active", [None, True, False])
+@pytest.mark.parametrize("case", ["stacked", "done", "fifo", "mixed"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_drop_instance_dense_matches_scatter_form(case, active, seed):
+    import jax.numpy as jnp
+    s, inst = _random_drop_state(seed, case)
+    act = None if active is None else jnp.asarray(active)
+    dense = sim._drop_instance(PARAMS, WL, s, inst, active=act)
+    oracle = _scatter_drop(PARAMS, WL, s, inst, active=act)
+    if case == "stacked" and active is not False:
+        # several tails come off one PE: the roll-back keeps task order
+        assert _lost_per_pe(s, inst).max() >= 2
+    if case == "fifo":
+        assert (np.asarray(WL.inst_id)[np.asarray(s.ready_ids)[
+            : int(s.ready_cnt)]] == inst).any()
+    _assert_drop_matches(dense, oracle)
+    if active is False:
+        # an inactive gate is the identity
+        for name in sim.SimState._fields:
+            assert np.array_equal(np.asarray(getattr(dense, name)),
+                                  np.asarray(getattr(s, name)),
+                                  equal_nan=True), name
+
+
+def test_drop_instance_dense_matches_scatter_form_vmapped():
+    """The batched engine's form: one lane per state, gates mixed."""
+    import jax
+    cases = ["stacked", "done", "fifo", "mixed"] * 2
+    states = [_random_drop_state(10 + k, c) for k, c in enumerate(cases)]
+    s = jax.tree_util.tree_map(lambda *x: np.stack(x),
+                               *[st_ for st_, _ in states])
+    insts = np.array([i for _, i in states], np.int32)
+    active = np.arange(len(cases)) % 3 != 2
+    wl = workloads.stack_workloads([WL] * len(cases))
+
+    def lanes(fn):
+        return jax.vmap(lambda w, st_, i, a: fn(PARAMS, w, st_, i, a))(
+            wl, s, insts, active)
+
+    dense, oracle = lanes(sim._drop_instance), lanes(_scatter_drop)
+    for k in range(len(cases)):
+        _assert_drop_matches(jax.tree_util.tree_map(lambda x: x[k], dense),
+                             jax.tree_util.tree_map(lambda x: x[k], oracle))

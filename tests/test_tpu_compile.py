@@ -11,6 +11,8 @@ one process may load the TPU library at a time, and every test worker
 imports this file. All compile tests live in this one file so a single
 worker owns the library.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -74,19 +76,57 @@ def test_push_rows_compiles(one_chip):
     assert _has_kernel(compiled)
 
 
+def _stacked_workloads():
+    """S scenarios of the default suite, stacked on the lane axis."""
+    suite = workloads.default_suite(n_instances=N_INSTANCES)
+    return suite.build_many([(mi, ri) for mi in range(4)
+                             for ri in (0, 5, 9, 13)])
+
+
+def _shapes(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype,
+                                       sharding=sharding), tree)
+
+
 @pytest.mark.parametrize("mode", [sim.MODE_ETF, sim.MODE_DAS])
 def test_simulate_batch_compiles_with_pallas(one_chip, mode):
-    suite = workloads.default_suite(n_instances=N_INSTANCES)
-    stacked = suite.build_many([(mi, ri) for mi in range(4)
-                                for ri in (0, 5, 9, 13)])
+    stacked = _stacked_workloads()
 
     def shapes(tree):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype,
-                                           sharding=one_chip), tree)
+        return _shapes(tree, one_chip)
 
     compiled = sim._simulate_batch.lower(
         mode, shapes(sim.make_params()), shapes(stacked),
         shapes(sim.always_fast_tree()), shapes(np.float32(1e9)), None,
         None, None, None, None, "pallas", flt.NO_CAPS).compile()
     assert _has_kernel(compiled)
+
+
+def test_fault_phases_compile_without_lane_flattened_scatters(one_chip):
+    """Under `vmap`, a gated scatter over a task row becomes one scatter
+    over lanes x tasks flattened into a single axis, which the TPU walks
+    update by update on every trip; a gather of a per-PE row for every
+    task (`taus[pe_of]`) is walked the same way. The fault phases write
+    task rows and per-PE rows densely and select each task's kill times
+    from the PE rows, so the loop holds neither."""
+    stacked = _stacked_workloads()
+    plan = flt.stack_plans([flt.with_deadline(flt.random_plan(k), 22.0)
+                            for k in range(S)])
+
+    def shapes(tree):
+        return _shapes(tree, one_chip)
+
+    compiled = sim._simulate_batch.lower(
+        sim.MODE_ETF, shapes(sim.make_params()), shapes(stacked),
+        shapes(sim.always_fast_tree()), shapes(np.float32(1e9)),
+        shapes(plan), None, None, 0, None, "pallas", flt.FULL_CAPS).compile()
+    text = compiled.as_text()
+    assert _has_kernel(compiled)
+    T = stacked.task_type.shape[1]
+    Tp = -(-T // sim.SEG) * sim.SEG
+    flat = {S * T, S * Tp, S * P}
+    sizes = [int(n) for n in re.findall(r"= \w+\[(\d+)\]\S* scatter\(", text)]
+    assert not flat & set(sizes), sizes
+    rows = re.findall(r"= \w+\[(\d+),(\d+),\d+\]\S* gather\(", text)
+    assert (str(S), str(T)) not in rows, rows
