@@ -11,16 +11,21 @@ A run is a closed loop with one client. A request hands the program the
 specs of one scenario per lane, and the program builds the workloads
 (`workloads.build_workload`), sweeps them in one chunk
 (`simulator.run_batch`) and returns the results to the host
-(`jax.device_get`). Set-up warms the cell's one program with a request of
-the same shapes and one frame per lane. The window runs whole requests
-until `--seconds` have passed; with `--trace 1` its first request runs
-under the profiler and with the engine's occupancy telemetry. After the
-window the reference checks a sample of the lanes (`check.py`).
+(`jax.device_get`). The configuration is the one source of what the
+program runs: its tables go to the program, its task graphs and
+ready-queue size go where the program takes them, and every other value
+the reference reads must equal the program's own (`handover`). Set-up
+warms the cell's one program with a request of the same shapes and one
+frame per lane. The window runs whole requests until `--seconds` have
+passed; with `--trace 1` its first request runs under the profiler and
+with the engine's occupancy telemetry. After the window the reference
+checks a sample of the lanes (`check.py`).
 """
 from __future__ import annotations
 
 import faulthandler
 import importlib.util
+import inspect
 import json
 import os
 import sys
@@ -73,13 +78,18 @@ def load_cell(name: str, bench_file: str | None = None):
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
     with open(os.path.join(ROOT, conf["file"])) as f:
         cfg = json.load(f)
+    traffic = traffic_mod.load(w["traffic"])
+    if traffic["mixes"].shape[1] != len(cfg["apps"]):
+        raise Failure(f"grid {traffic['grid']!r}: a mix has "
+                      f"{traffic['mixes'].shape[1]} fractions, configuration "
+                      f"{conf['name']!r} has {len(cfg['apps'])} apps")
 
     def applies(m):
         return "workloads" not in m or name in m["workloads"]
 
     return types.SimpleNamespace(
         name=name, chips=int(w["chips"]), cfg=cfg,
-        traffic=traffic_mod.load(w["traffic"]),
+        traffic=traffic,
         end_to_end=[m for m in bench["end_to_end"] if applies(m)],
         per_layer=[m for m in bench["per_layer"] if applies(m)],
         check=check.load(name))
@@ -97,13 +107,98 @@ def reader(metric: str):
 # ---------------------------------------------------------------------------
 # the system under test
 # ---------------------------------------------------------------------------
+# Values of the scheduler and stream model that the reference reads from
+# the configuration and the program holds as constants (module, names):
+# they have to be equal.
+CONSTANTS = {
+    "stream.frame_kbits": ("workloads", ("FRAME_KBITS",)),
+    "platform.rate_register_entries": ("simulator", ("RING",)),
+    "platform.lut_latency_us": ("soc", ("LUT_LATENCY_US",)),
+    "platform.lut_energy_uj": ("soc", ("LUT_ENERGY_UJ",)),
+    "platform.das_classifier_energy_uj": ("soc", ("DAS_CLS_ENERGY_UJ",)),
+    "platform.etf_latency_us_poly": ("soc", ("ETF_LAT_C0", "ETF_LAT_C1",
+                                             "ETF_LAT_C2")),
+    "platform.scheduler_power_w": ("soc", ("SCHED_POWER_W",)),
+}
+
+
+def app_graphs(dfg, cfg) -> list:
+    """The configuration's task graphs as the program's `dfg.AppGraph`s,
+    in the configuration's order, task types indexed by
+    `platform.task_types`."""
+    type_id = {t: k for k, t in enumerate(cfg["platform"]["task_types"])}
+    return [dfg.AppGraph(np.asarray([type_id[t] for t, _, _ in rows],
+                                    np.int32),
+                         [tuple(p) for _, p, _ in rows],
+                         np.asarray([kb for _, _, kb in rows], np.float32))
+            for rows in cfg["apps"].values()]
+
+
+def _same_graphs(a, b) -> bool:
+    return len(a) == len(b) and all(
+        np.array_equal(x.task_types, y.task_types)
+        and [tuple(p) for p in x.preds] == [tuple(p) for p in y.preds]
+        and np.array_equal(x.out_kb, y.out_kb) for x, y in zip(a, b))
+
+
+def handover(cfg, mods) -> dict:
+    """Keyword arguments that hand the program what the configuration
+    declares beyond its tables: {"suite": for `workloads.default_suite`,
+    "build": for `workloads.build_workload`, "run": for
+    `simulator.run_batch`}. The task graphs go as `apps` and the
+    ready-queue size as `ready_slots` where the program takes a parameter
+    of that name; where it takes none, and for the values in `CONSTANTS`,
+    the configuration's value has to equal the program's own, or `Failure`
+    names every key that differs. `mods` maps "dfg", "soc", "simulator"
+    and "workloads" to the program's modules."""
+    dfg, sim, wl = mods["dfg"], mods["simulator"], mods["workloads"]
+    kw = {"suite": {}, "build": {}, "run": {}}
+    bad = []
+
+    def takes(fn, name):
+        return name in inspect.signature(fn).parameters
+
+    apps = app_graphs(dfg, cfg)
+    if takes(wl.build_workload, "apps") and takes(wl.default_suite, "apps"):
+        kw["suite"]["apps"] = kw["build"]["apps"] = apps
+    elif not _same_graphs(apps, list(dfg.APPS.values())):
+        bad.append("apps: the task graphs differ from the program's "
+                   "dfg.APPS, and workloads.build_workload / default_suite "
+                   "take no `apps`")
+    slots = int(cfg["platform"]["ready_queue_slots"])
+    own_slots = getattr(sim, "R_MAX", None)
+    if takes(sim.run_batch, "ready_slots"):
+        kw["run"]["ready_slots"] = slots
+    elif slots != own_slots:
+        bad.append(f"platform.ready_queue_slots: {slots}, the program's "
+                   f"simulator.R_MAX is {own_slots}, and run_batch takes "
+                   "no `ready_slots`")
+    for key, (mod, names) in CONSTANTS.items():
+        group, leaf = key.split(".")
+        value = cfg[group][leaf]
+        own = [getattr(mods[mod], n, None) for n in names]
+        if None in own or not np.array_equal(
+                np.asarray(value, np.float32).reshape(-1),
+                np.asarray(own, np.float32)):
+            bad.append(f"{key}: {value}, the program's {mod}."
+                       f"{'/'.join(names)}: "
+                       f"{', '.join(str(v) for v in own)}")
+    if bad:
+        raise Failure("the program cannot run this configuration as its "
+                      "check does: " + "; ".join(bad))
+    return kw
+
+
 class Program:
     """The sweep engine, driven the way a user drives it."""
 
     def __init__(self, cell, devices):
         import jax.numpy as jnp
-        from repro.core import faults, simulator as sim, soc, workloads
+        from repro.core import dfg, faults, simulator as sim, soc, workloads
         self._faults, self._sim, self._wl = faults, sim, workloads
+        self.kw = handover(cell.cfg, {"dfg": dfg, "soc": soc,
+                                      "simulator": sim,
+                                      "workloads": workloads})
         self.devices = devices
         plat = cell.cfg["platform"]
         n = plat["pes_per_cluster"]
@@ -130,13 +225,15 @@ class Program:
             self.tree = sim.DTree(feat=jnp.asarray(t["feat"], jnp.int32),
                                   thr=jnp.asarray(t["thr"], jnp.float32),
                                   leaf=jnp.asarray(t["leaf"], jnp.int32))
-        suite = workloads.default_suite(n_instances=cell.traffic["frames"])
+        suite = workloads.default_suite(n_instances=cell.traffic["frames"],
+                                        **self.kw["suite"])
         self.t_max, self.i_max = suite.t_max, suite.i_max
 
     def build(self, lanes):
         wls = [self._wl.build_workload(l.mix, l.rate_mbps, l.frames,
                                        seed=l.seed, t_max=self.t_max,
-                                       i_max=self.i_max) for l in lanes]
+                                       i_max=self.i_max, **self.kw["build"])
+               for l in lanes]
         plan = None
         if lanes[0].plan is not None:
             fp = self._faults.FaultPlan
@@ -151,7 +248,7 @@ class Program:
         return self._sim.run_batch(self.mode, wls, self.params,
                                    tree=self.tree, batch_size=len(wls),
                                    plan=plan, devices=self.devices,
-                                   telemetry=telemetry)
+                                   telemetry=telemetry, **self.kw["run"])
 
 
 def request(jax, prog: Program, lanes, telemetry=None) -> dict:

@@ -1,6 +1,8 @@
 """A cell at a tiny size on the CPU, driven through the harness's run
 with the look for a chip skipped."""
 import faulthandler
+import json
+import os
 import time
 
 from bench import harness, traffic
@@ -35,3 +37,15 @@ def run_tiny(name, trace=False, seconds=0.3, cell=None):
                            time.perf_counter(), jax)
     finally:
         faulthandler.cancel_dump_traceback_later()
+
+
+def fanout_config():
+    """soc19 with a sixth application whose root fans out to 24 tasks,
+    and a ready queue of 32 slots to hold them."""
+    with open(os.path.join(harness.HERE, "configs", "soc19.json")) as f:
+        cfg = json.load(f)
+    cfg["name"] = "soc19_fanout"
+    cfg["apps"]["fanout"] = ([["sync", [], 8.0]] + [["fft", [0], 8.0]] * 24
+                             + [["demod", list(range(1, 25)), 2.0]])
+    cfg["platform"]["ready_queue_slots"] = 32
+    return cfg
